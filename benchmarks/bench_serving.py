@@ -584,7 +584,7 @@ def sharded_tp_scaling() -> None:
     stays degree-invariant (same workload, same totals, same baselines).
     Runs in a subprocess because the mesh needs forced host devices
     (XLA_FLAGS must be set before jax import; the in-process benches
-    need the real single CPU device). Gates: token-identical outputs,
+    need the real single CPU device), pinned to the CPU platform. Gates: token-identical outputs,
     one step compile, and per-device weight-stream bytes/token at TP=2
     <= 0.55x TP=1 (the exact factor is 0.5)."""
     with tempfile.NamedTemporaryFile("w", suffix=".py", delete=False) as f:
@@ -595,6 +595,9 @@ def sharded_tp_scaling() -> None:
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
     env.pop("XLA_FLAGS", None)
+    # The worker checks modeled bytes on forced host devices: pin it to
+    # the CPU so it never reaches for an accelerator this process holds.
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         proc = subprocess.run([sys.executable, worker], capture_output=True,
                               text=True, timeout=1800, env=env)

@@ -68,10 +68,17 @@ def end_of_k(o_ref, acc_ref) -> None:
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def interpret_kernels() -> bool:
+    """The one backend decision for every Pallas kernel: compiled by
+    Mosaic on a TPU, run through the Pallas interpreter only where the
+    default backend is the CPU (tests, CPU rehearsals). Jitted programs
+    read it once, where they are built, and pass it down."""
+    return jax.default_backend() == "cpu"
+
+
 def matmul_compiler_params():
-    # jax 0.4.x names this TPUCompilerParams; >= 0.6 renamed it.
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:

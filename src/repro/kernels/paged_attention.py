@@ -14,8 +14,12 @@ online-softmax skeleton of ``kernels/flash_attention.py``).
 Layout contract (see ``PagedKVArena.page_layout``):
 
   q        (B, C, H, D)        — C >= 1 chunked-decode queries per slot
-  k_pages  (NP, bs, Hkv, D)    — physical pages incl. the trailing null
-  v_pages  (NP, bs, Hkv, Dv)     page; H % Hkv == 0 (GQA groups)
+  k_pages  (NP, Hkv, bs, D)    — head-major physical pages incl. the
+  v_pages  (NP, Hkv, bs, Dv)     trailing null page; H % Hkv == 0 (GQA
+                                 groups). One kernel block is one
+                                 (page, kv-head) tile (bs, D): its last
+                                 two dims are the array's own, which is
+                                 what Mosaic's (8, 128) tiling rule needs
   tables   (B, MB) int32       — logical block -> physical page; entries
                                  past a slot's allocation hold the null
                                  page id (finite garbage, always masked)
@@ -31,12 +35,14 @@ attended in place, never expanded *and* never gathered.
 
 Quantized pages (``k_scales`` passed): K/V pages hold int8 codes and a
 parallel *scale page* per pages operand carries one float16 scale per
-(in-page position, kv-head) — ``(NP, bs, Hkv)`` next to ``(NP, bs,
-Hkv, D)`` — quantized over the feature axis at insert time (see
-``PagedKVArena.page_layout``). The kernel dequantizes in VMEM inside
-the block walk (``k = int8 * scale`` in f32, same for V and the MLA
-RoPE side), so the per-block DMA moves ~(D+2)/(2D) of the bf16 bytes
-and the arithmetic is unchanged f32 online softmax. A zeroed page
+(kv-head, in-page position) — ``(NP, Hkv, bs)`` next to ``(NP, Hkv,
+bs, D)`` — quantized over the feature axis at insert time (see
+``PagedKVArena.page_layout``). A scale block is the whole ``(Hkv, bs)``
+page (full dims, so it tiles); the kernel picks its kv-head's row and
+dequantizes in VMEM inside the block walk (``k = int8 * scale`` in
+f32, same for V and the MLA RoPE side), so the per-block DMA moves
+~(D+2)/(2D) of the bf16 bytes and the arithmetic is unchanged f32
+online softmax. A zeroed page
 dequantizes to exactly zero (code 0 x scale 0), so the arena's
 rollback/CoW/null-page contracts carry over bit-for-bit.
 
@@ -48,7 +54,9 @@ DMA) and ``pl.when`` skips the compute. Per-step KV bytes are therefore
 O(live tokens), not O(arena).
 
 ``interpret=True`` runs the same kernel body through the Pallas
-interpreter so CPU CI exercises the exact serving code path.
+interpreter so CPU CI exercises the exact serving code path; callers
+take the flag from ``kernels.common.interpret_kernels`` (compiled on a
+TPU, interpreted only on the CPU).
 """
 from __future__ import annotations
 
@@ -81,14 +89,15 @@ def _kernel(tables_ref, pos_ref, len_ref, *refs, sm_scale, block_size,
 
     def page(ref, s_ref):
         """(bs, D) f32 page tile, dequantized when the arena is int8:
-        code * per-(position, kv-head) scale — a zeroed page (code 0,
+        code * per-(kv-head, position) scale — a zeroed page (code 0,
         scale 0) dequantizes to exactly 0, preserving the rollback
         bit-identity contract on the quantized layout."""
-        x = ref[0, :, 0, :].astype(jnp.float32)
+        x = ref[0, 0].astype(jnp.float32)
         if s_ref is not None:
-            x = x * s_ref[0, :, 0].astype(jnp.float32)[:, None]
+            x = x * _scale_column(_f16_bits_to_f32(s_ref[0]), hkv_idx)
         return x
     b = pl.program_id(0)
+    hkv_idx = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
     pos0 = pos_ref[b]
@@ -141,6 +150,38 @@ def _kernel(tables_ref, pos_ref, len_ref, *refs, sm_scale, block_size,
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _f16_bits_to_f32(bits):
+    """Exact float16 -> float32 from int16 bit patterns. Mosaic cannot
+    load float16 on every TPU generation (v5e refuses the vector type),
+    so scale pages enter the kernel bitcast to int16 and are widened
+    here with integer ops: normals rebias the exponent, subnormals are
+    ``mantissa * 2**-24``, and inf/NaN keep an all-ones exponent."""
+    b = bits.astype(jnp.int32) & 0xFFFF
+    sign = b >> 15
+    exp = (b >> 10) & 0x1F
+    man = b & 0x3FF
+    exp32 = jnp.where(exp == 0x1F, 0xFF, exp + (127 - 15))
+    normal = jax.lax.bitcast_convert_type(
+        (sign << 31) | (exp32 << 23) | (man << 13), jnp.float32)
+    sub = man.astype(jnp.float32) * (2.0 ** -24)
+    sub = jnp.where(sign == 1, -sub, sub)
+    return jnp.where(exp == 0, sub, normal)
+
+
+def _scale_column(scales, h):
+    """Row ``h`` of an ``(Hkv, bs)`` scale tile as a ``(bs, 1)`` column.
+
+    Masked sums select the row and turn it into a column without a
+    dynamic sublane slice or a transpose of a narrow tile, which Mosaic
+    may refuse; each sum adds exact zeros, so the scale is unchanged."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 0)
+    row = jnp.sum(jnp.where(rows == h, scales, 0.0), axis=0, keepdims=True)
+    n = scales.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
 def _fold_heads(x, b, c, hkv, group):
     """(B, C, H, D) -> (B, Hkv, C*G, D): row r of a kv-head's query tile
     is (chunk entry r // G, group member r % G) — head h = hkv*G + g,
@@ -160,26 +201,27 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
                            interpret: bool = False):
     """Fused paged decode attention over a chunk of C queries per slot.
 
-    q: (B, C, H, D); k_pages/v_pages: (NP, bs, Hkv, D/Dv) physical pages
-    (NP includes the arena's trailing null page); block_tables: (B, MB)
-    int32; positions: (B,) int32 chunk base positions. ``v_pages=None``
-    shares the K pages as V (MLA's compressed latents are both — one
-    DMA per live block instead of two). Optional q2 (B, C, H, D2) /
-    k2_pages (NP, bs, Hkv, D2) contribute a second score term before
+    q: (B, C, H, D); k_pages/v_pages: (NP, Hkv, bs, D/Dv) head-major
+    physical pages (NP includes the arena's trailing null page);
+    block_tables: (B, MB) int32; positions: (B,) int32 chunk base
+    positions. ``v_pages=None`` shares the K pages as V (MLA's
+    compressed latents are both — one DMA per live block instead of
+    two). Optional q2 (B, C, H, D2) /
+    k2_pages (NP, Hkv, bs, D2) contribute a second score term before
     the softmax (MLA's decoupled-RoPE side). ``lengths`` (B,) int32:
     valid queries per row (chunked prefill) — each row's block walk
     stops at its last *valid* query's causal depth, so a steady-state
     decode row (lengths == 1) never over-fetches for its garbage tail.
 
     Quantized arenas pass int8 pages plus ``k_scales``/``v_scales``/
-    ``k2_scales`` — (NP, bs, Hkv) per-(position, kv-head) scale pages
+    ``k2_scales`` — (NP, Hkv, bs) per-(kv-head, position) scale pages
     riding the same block-table index map — and the kernel dequantizes
     inside the block walk (all scale operands must accompany their
     pages; ``v_scales`` is omitted exactly when ``v_pages`` is).
     Returns (B, C, H, Dv) in ``out_dtype`` (default q.dtype).
     """
     b, c, h, d = q.shape
-    num_pages, bs, hkv, _ = k_pages.shape
+    num_pages, hkv, bs, _ = k_pages.shape
     shared_kv = v_pages is None
     quantized = k_scales is not None
     if quantized:
@@ -203,42 +245,45 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
         # re-fetch of an unchanged block index, so trailing table entries
         # cost no DMA (the compute is skipped by pl.when).
         last = (pos[bb] + jnp.maximum(lens[bb], 1) - 1) // bs
-        return (tables[bb, jnp.minimum(jj, last)], 0, hh, 0)
+        return (tables[bb, jnp.minimum(jj, last)], hh, 0, 0)
 
     def q_index(bb, hh, jj, tables, pos, lens):
         return (bb, hh, 0, 0)
 
     def scale_index(bb, hh, jj, tables, pos, lens):
-        # Scale pages (NP, bs, Hkv) ride the same clamped table walk as
-        # their int8 pages — one extra (bs,) fetch per live block.
+        # Scale pages (NP, Hkv, bs) ride the same clamped table walk as
+        # their int8 pages — one extra (Hkv, bs) fetch per live block.
         last = (pos[bb] + jnp.maximum(lens[bb], 1) - 1) // bs
-        return (tables[bb, jnp.minimum(jj, last)], 0, hh)
+        return (tables[bb, jnp.minimum(jj, last)], 0, 0)
 
-    scale_spec = pl.BlockSpec((1, bs, 1), scale_index)
+    scale_spec = pl.BlockSpec((1, hkv, bs), scale_index)
+    # float16 scale pages travel as their int16 bits (see _f16_bits_to_f32).
+    bits = lambda s: jax.lax.bitcast_convert_type(
+        jnp.asarray(s, jnp.float16), jnp.int16)
     in_specs = [pl.BlockSpec((1, 1, cg, d), q_index)]
     args = [_fold_heads(q, b, c, hkv, group)]
     if has_rope:
         d2 = q2.shape[-1]
         in_specs.append(pl.BlockSpec((1, 1, cg, d2), q_index))
         args.append(_fold_heads(q2, b, c, hkv, group))
-    in_specs.append(pl.BlockSpec((1, bs, 1, d), page_index))
+    in_specs.append(pl.BlockSpec((1, 1, bs, d), page_index))
     args.append(k_pages)
     if quantized:
         in_specs.append(scale_spec)
-        args.append(k_scales)
+        args.append(bits(k_scales))
     if has_rope:
-        in_specs.append(pl.BlockSpec((1, bs, 1, k2_pages.shape[-1]),
+        in_specs.append(pl.BlockSpec((1, 1, bs, k2_pages.shape[-1]),
                                      page_index))
         args.append(k2_pages)
         if quantized:
             in_specs.append(scale_spec)
-            args.append(k2_scales)
+            args.append(bits(k2_scales))
     if not shared_kv:
-        in_specs.append(pl.BlockSpec((1, bs, 1, dv), page_index))
+        in_specs.append(pl.BlockSpec((1, 1, bs, dv), page_index))
         args.append(v_pages)
         if quantized:
             in_specs.append(scale_spec)
-            args.append(v_scales)
+            args.append(bits(v_scales))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -251,7 +296,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
             pltpu.VMEM((cg, 1), jnp.float32),
         ],
     )
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
     out = pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, block_size=bs,
                           group=group, has_rope=has_rope,
@@ -259,7 +303,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, cg, dv),
                                        out_dtype or q.dtype),
-        compiler_params=cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), positions, lengths, *args)

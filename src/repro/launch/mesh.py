@@ -5,25 +5,22 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
-def _make_mesh(shape, axes):
-    # jax >= 0.5 takes axis_types (explicit-sharding API); 0.4.x does not.
-    kw = {}
-    if "axis_types" in inspect.signature(jax.make_mesh).parameters \
-            and hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices, in the
+    device order it picks for the physical topology, with every axis
+    ``Auto`` (GSPMD propagates shardings from the committed inputs)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips single-pod; 2x16x16 = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -31,4 +28,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(min(model, n // data), 1)
-    return _make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

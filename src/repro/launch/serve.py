@@ -63,16 +63,38 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from repro.runtime.engine import Engine, ServingEngine
 from repro.runtime.request import Request, SamplingParams
 from repro.runtime.telemetry import serve_report_lines
+
+
+#: The one persistent compilation cache directory used when
+#: ``JAX_COMPILATION_CACHE_DIR`` is not set: fixed, inside the checkout
+#: and ignored by git (the path is part of the cache key, so it never
+#: moves between runs).
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory and
+    return it. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and nothing is set here; otherwise the cache goes to
+    ``COMPILE_CACHE_DIR``. Entry points call this, never an import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def make_extras(cfg, batch: int):
@@ -356,9 +378,7 @@ def build_mesh(args):
     degrees are 1 (single-device serving, no GSPMD partitioning)."""
     if args.dp * args.tp == 1:
         return None
-    devs = np.array(jax.devices()[: args.dp * args.tp])
-    return jax.sharding.Mesh(devs.reshape(args.dp, args.tp),
-                             ("data", "model"))
+    return make_mesh((args.dp, args.tp), ("data", "model"))
 
 
 def main() -> None:
@@ -454,6 +474,7 @@ def main() -> None:
                          "tracks); open it at https://ui.perfetto.dev")
     args = ap.parse_args()
     validate_args(ap, args)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -56,12 +56,15 @@ class ModelAPI:
         """Paged-arena storage layout: returns ``(shapes, paged)`` pytrees.
 
         Leaves whose extent follows the sequence length (attention K/V,
-        MLA latents) become physical pages ``(count, num_physical,
-        block_size, ...)`` with ``paged`` True; constant-size leaves (SSM
-        recurrent/conv state, enc-dec cross KV) keep per-slot storage
-        ``(count, num_slots, ...)`` with ``paged`` False. Detection probes
-        ``cache_shapes`` at two sequence lengths and pages exactly the
-        leaves/axes that moved — no per-family special-casing."""
+        MLA latents) become head-major physical pages ``(count,
+        num_physical, heads, block_size, feature)`` with ``paged`` True —
+        GQA keeps its kv-head axis, MLA latents get a unit one, so every
+        page is a run of ``(block_size, feature)`` tiles the fused kernel
+        reads whole. Constant-size leaves (SSM recurrent/conv state,
+        enc-dec cross KV) keep per-slot storage ``(count, num_slots,
+        ...)`` with ``paged`` False. Detection probes ``cache_shapes`` at
+        two sequence lengths and pages exactly the leaves/axes that moved
+        — no per-family special-casing."""
         s_a, s_b = 160, 224      # probe lengths; avoid constant-dim collisions
         ta = self.cache_shapes(num_slots, s_a)
         tb = self.cache_shapes(num_slots, s_b)
@@ -71,9 +74,10 @@ class ModelAPI:
             if sa == sb:
                 return sa
             diff = [i for i, (x, y) in enumerate(zip(sa, sb)) if x != y]
-            assert diff == [2] and sa[2] == s_a, \
+            assert diff == [2] and sa[2] == s_a and len(sa) in (4, 5), \
                 f"unsupported cache layout for paging: {sa} vs {sb}"
-            return (sa[0], num_physical, block_size) + sa[3:]
+            heads = sa[3:-1] or (1,)
+            return (sa[0], num_physical) + heads + (block_size, sa[-1])
 
         shapes = jax.tree.map(pick, ta, tb, is_leaf=is_shape)
         paged = jax.tree.map(lambda sa, sb: sa != sb, ta, tb,
@@ -90,7 +94,8 @@ class ModelAPI:
         null block). ``kv_quant="int8"`` mirrors the quantized arena
         layout: each paged leaf becomes ``{"q": int8 pages, "s": float16
         scale pages}`` (scale shape = page shape minus the feature
-        axis), matching ``PagedKVArena.page_layout``."""
+        axis: ``(count, pages, heads, block_size)``), matching
+        ``PagedKVArena.page_layout``."""
         shapes, paged = self.paged_cache_shapes(num_slots, num_blocks + 1,
                                                 block_size)
         is_shape = lambda x: isinstance(x, tuple)

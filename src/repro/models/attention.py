@@ -18,10 +18,11 @@ KV caches:
   MLA:  {"ckv": (B, S, kv_lora_rank), "krope": (B, S, rope_dim)}  (compressed;
         decode uses the absorbed-matmul form so the cache is never expanded)
 
-Paged decode (``block_tables`` passed): cache leaves are physical pages —
-GQA {"k": (NB, bs, Hkv, D), ...}, MLA {"ckv": (NB, bs, rank), ...} — and
-``block_tables`` (B, max_blocks) int32 maps each slot's logical blocks to
-pages. The new token is scattered to its page, then attention reads K/V
+Paged decode (``block_tables`` passed): cache leaves are head-major
+physical pages — GQA {"k": (NB, Hkv, bs, D), ...}, MLA {"ckv": (NB, 1,
+bs, rank), ...} (the latents carry a unit head axis, so every paged
+leaf has its position axis at 2) — and ``block_tables`` (B,
+max_blocks) int32 maps each slot's logical blocks to pages. The new token is scattered to its page, then attention reads K/V
 through the table one of two ways (``paged_impl``):
 
 * ``"fused"`` (default): the Pallas blocked online-softmax kernel in
@@ -35,8 +36,8 @@ through the table one of two ways (``paged_impl``):
 
 Quantized paged cache (arena built with ``kv_quant="int8"``): each paged
 leaf is a dict ``{"q": int8 code pages, "s": float16 scale pages}``
-(scale per (position, kv-head), quantized over the feature axis at
-insert time — see ``quantize_kv``). The decode paths detect the dict
+(scale pages (NB, Hkv, bs): one scale per (kv-head, position),
+quantized over the feature axis at insert time — see ``quantize_kv``). The decode paths detect the dict
 structurally, quantize on insert, and either hand code+scale pages to
 the fused kernel (which dequantizes in the block walk) or dequantize the
 dense gathered view on the ref path. Contiguous caches are never
@@ -53,6 +54,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.models import flags, layers
 from repro.models.layers import Params
+from repro.parallel import sharding as shard_rules
 
 NEG_INF = -1e30
 
@@ -356,16 +358,17 @@ def _paged_view_dequant(leaf: Dict, block_tables) -> jnp.ndarray:
 def paged_insert_token(pages: jnp.ndarray, new: jnp.ndarray, position,
                        block_tables: jnp.ndarray,
                        lengths=None) -> jnp.ndarray:
-    """Scatter (B, C, ...) ``new`` into (NB, bs, ...) ``pages`` routed
-    through ``block_tables`` (B, max_blocks). C == 1 with ``lengths`` None
-    is the classic one-token write at ``position``; the chunk form writes
-    row b's first ``lengths[b]`` entries at ``position[b] + i``.
+    """Scatter (B, C, H, ...) ``new`` into head-major (NB, H, bs, ...)
+    ``pages`` routed through ``block_tables`` (B, max_blocks). C == 1
+    with ``lengths`` None is the classic one-token write at
+    ``position``; the chunk form writes row b's first ``lengths[b]``
+    entries at ``position[b] + i``.
 
     Blocks are uniquely owned by one slot, so active slots never collide;
     single-token writes from inactive slots land in the arena's null block
     (their table entries all point there), while chunk writes past a row's
     valid length are routed out of range and *dropped* by the scatter."""
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     b, c = new.shape[:2]
     pos0 = jnp.broadcast_to(jnp.asarray(position), (b,))
     pos = pos0[:, None] + jnp.arange(c)                  # (B, C)
@@ -374,17 +377,52 @@ def paged_insert_token(pages: jnp.ndarray, new: jnp.ndarray, position,
     if lengths is not None:
         valid = jnp.arange(c)[None, :] < lengths[:, None]
         phys = jnp.where(valid, phys, pages.shape[0])    # OOB -> dropped
-    return pages.at[phys, pos % bs].set(new.astype(pages.dtype),
-                                        mode="drop")
+    # Indices on axes 0 and 2 around a slice: the (B, C) index dims lead
+    # the update, so ``new`` (B, C, H, ...) lines up as it is.
+    return pages.at[phys, :, pos % bs].set(new.astype(pages.dtype),
+                                           mode="drop")
 
 
 def paged_view(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-    """Gather each slot's logical KV view (B, max_blocks*bs, ...) from
-    (NB, bs, ...) pages. Unassigned table entries gather the null block;
-    those positions sit past kv_len and are masked to NEG_INF before the
-    softmax, so their (finite) garbage never contributes."""
-    v = pages[block_tables]                      # (B, max_blocks, bs, ...)
+    """Gather each slot's logical KV view (B, max_blocks*bs, H, ...) from
+    head-major (NB, H, bs, ...) pages. Unassigned table entries gather the
+    null block; those positions sit past kv_len and are masked to NEG_INF
+    before the softmax, so their (finite) garbage never contributes."""
+    v = jnp.swapaxes(pages[block_tables], 2, 3)  # (B, max_blocks, bs, H, ...)
     return v.reshape((v.shape[0], v.shape[1] * v.shape[2]) + v.shape[3:])
+
+
+def fused_paged_attention(q, k_pages, v_pages, block_tables, base, *,
+                          lengths=None, q2=None, k2_pages=None,
+                          k_scales=None, v_scales=None, k2_scales=None,
+                          **kw):
+    """``paged_decode_attention`` as the decode layers call it. Traced
+    under a serving mesh (``shard_rules.activation_mesh``), the kernel
+    runs per shard inside a ``shard_map`` — Mosaic kernels cannot be
+    partitioned automatically — with the specs of
+    ``shard_rules.paged_kernel_specs``; each shard attends its own slots
+    and kv-heads, so per-slot results are those of one device."""
+    b, c = q.shape[:2]
+    if lengths is None:
+        lengths = jnp.full((b,), c, jnp.int32)
+    ops = {"q": q, "k_pages": k_pages, "v_pages": v_pages,
+           "block_tables": block_tables, "positions": base,
+           "lengths": lengths, "q2": q2, "k2_pages": k2_pages,
+           "k_scales": k_scales, "v_scales": v_scales,
+           "k2_scales": k2_scales}
+    ops = {k: v for k, v in ops.items() if v is not None}
+    mesh = shard_rules.traced_serving_mesh()
+    if mesh is None:
+        return paged_decode_attention(**{"v_pages": None, **ops}, **kw)
+    per_slot, pages, rows, out = shard_rules.paged_kernel_specs(
+        mesh, b, q.shape[2], k_pages.shape[1])
+    spec = {"q": per_slot, "q2": per_slot, "block_tables": rows,
+            "positions": rows, "lengths": rows}
+    return jax.shard_map(
+        lambda *xs: paged_decode_attention(
+            **{"v_pages": None, **dict(zip(ops, xs))}, **kw),
+        mesh=mesh, out_specs=out, check_vma=False,
+        in_specs=tuple(spec.get(k, pages) for k in ops))(*ops.values())
 
 
 def decode_positions(position, batch: int, width: int) -> jnp.ndarray:
@@ -404,8 +442,9 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                paged_impl: str = "fused"):
     """Decode step over a chunk of C tokens. x: (B, C, d); ``position``:
     scalar int32 or (B,) base position (chunk entry i sits at base + i);
-    cache {"k","v"}: (B, S, Hkv, D) pre-allocated — or physical pages
-    (NB, bs, Hkv, D) when ``block_tables`` (B, max_blocks) is passed.
+    cache {"k","v"}: (B, S, Hkv, D) pre-allocated — or head-major
+    physical pages (NB, Hkv, bs, D) when ``block_tables`` (B, max_blocks)
+    is passed.
     C == 1 is the classic one-token step. Returns (out, cache).
 
     ``lengths``: (B,) valid entries per row (chunked prefill: a slot may
@@ -442,16 +481,16 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
         if paged_impl == "fused":
             base = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
             if kv_quant:
-                o = paged_decode_attention(
+                o = fused_paged_attention(
                     q, kp["q"], vp["q"], block_tables, base,
                     sm_scale=hd ** -0.5, k_scales=kp["s"],
                     v_scales=vp["s"], lengths=lengths,
                     interpret=interpret)
             else:
-                o = paged_decode_attention(q, kp, vp, block_tables, base,
-                                           sm_scale=hd ** -0.5,
-                                           lengths=lengths,
-                                           interpret=interpret)
+                o = fused_paged_attention(q, kp, vp, block_tables, base,
+                                          sm_scale=hd ** -0.5,
+                                          lengths=lengths,
+                                          interpret=interpret)
             o = o.reshape(b, cw, cfg.num_heads * hd)
             out = layers.linear_apply(p["o"], o, fmt, impl=impl,
                                       interpret=interpret)
@@ -568,8 +607,9 @@ def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
     the scalar/(B,) base and ``lengths`` the per-row valid count (chunked
     prefill), masking exactly like the GQA chunk path.
 
-    With ``block_tables``, cache leaves are physical pages (NB, bs, ...)
-    and the compressed latents are scattered through the table. The
+    With ``block_tables``, cache leaves are physical pages (NB, 1, bs,
+    ...) — a unit head axis, the paged layout's shared shape — and the
+    compressed latents are scattered through the table. The
     default ``paged_impl="fused"`` attends the pages in place: the fused
     kernel takes the absorbed ``q_eff`` against the ckv pages (which are
     also V) and joins the decoupled-RoPE scores via its q2/k2 operands —
@@ -584,24 +624,16 @@ def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
     fused = block_tables is not None and paged_impl == "fused"
     kv_quant = block_tables is not None and isinstance(cache["ckv"], dict)
     if block_tables is not None:
-        if kv_quant:
-            ckv_p = _paged_insert_quant(cache["ckv"], ckv_new, position,
-                                        block_tables, lengths)
-            krope_p = _paged_insert_quant(cache["krope"], krope_new,
-                                          position, block_tables, lengths)
-        else:
-            ckv_p = paged_insert_token(cache["ckv"], ckv_new, position,
-                                       block_tables, lengths)
-            krope_p = paged_insert_token(cache["krope"], krope_new,
-                                         position, block_tables, lengths)
+        insert = _paged_insert_quant if kv_quant else paged_insert_token
+        view = _paged_view_dequant if kv_quant else paged_view
+        ckv_p = insert(cache["ckv"], ckv_new[:, :, None], position,
+                       block_tables, lengths)
+        krope_p = insert(cache["krope"], krope_new[:, :, None], position,
+                         block_tables, lengths)
         cache = {"ckv": ckv_p, "krope": krope_p}
         if not fused:
-            if kv_quant:
-                ckv = _paged_view_dequant(ckv_p, block_tables)
-                krope = _paged_view_dequant(krope_p, block_tables)
-            else:
-                ckv = paged_view(ckv_p, block_tables)
-                krope = paged_view(krope_p, block_tables)
+            ckv = view(ckv_p, block_tables)[:, :, 0]
+            krope = view(krope_p, block_tables)[:, :, 0]
     else:
         ckv = _insert_kv(cache["ckv"], ckv_new, position, lengths)
         krope = _insert_kv(cache["krope"], krope_new, position, lengths)
@@ -619,23 +651,21 @@ def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
     if fused:
         base = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
         if kv_quant:
-            # Quantized compressed latents: int8 code pages + (NP, bs)
-            # scale pages, lifted to the kernel's Hkv == 1 layout.
-            ctx = paged_decode_attention(
-                q_eff, ckv_p["q"][:, :, None, :], None,  # ckv doubles as V
+            # Quantized compressed latents: int8 code pages + (NP, 1, bs)
+            # scale pages — already the kernel's Hkv == 1 layout.
+            ctx = fused_paged_attention(
+                q_eff, ckv_p["q"], None,                 # ckv doubles as V
                 block_tables, base, sm_scale=sm,
-                q2=q_rope.astype(jnp.float32),
-                k2_pages=krope_p["q"][:, :, None, :],
-                k_scales=ckv_p["s"][:, :, None],
-                k2_scales=krope_p["s"][:, :, None], lengths=lengths,
-                out_dtype=jnp.float32, interpret=interpret)
+                q2=q_rope.astype(jnp.float32), k2_pages=krope_p["q"],
+                k_scales=ckv_p["s"], k2_scales=krope_p["s"],
+                lengths=lengths, out_dtype=jnp.float32, interpret=interpret)
         else:
-            ctx = paged_decode_attention(
-                q_eff, ckv_p[:, :, None, :], None,       # ckv doubles as V
+            ctx = fused_paged_attention(
+                q_eff, ckv_p, None,                      # ckv doubles as V
                 block_tables, base, sm_scale=sm,
-                q2=q_rope.astype(jnp.float32),
-                k2_pages=krope_p[:, :, None, :], lengths=lengths,
-                out_dtype=jnp.float32, interpret=interpret)  # (b,q,h,rank)
+                q2=q_rope.astype(jnp.float32), k2_pages=krope_p,
+                lengths=lengths, out_dtype=jnp.float32,
+                interpret=interpret)                     # (b,q,h,rank)
         o = jnp.einsum("bqhr,hvr->bqhv", ctx, wv)
         o = o.reshape(b, cw, h * m.v_head_dim).astype(x.dtype)
         out = layers.linear_apply(p["o"], o, fmt, impl=impl,

@@ -150,7 +150,6 @@ def replicated(mesh: Mesh):
 
 _SERVING_REPLICATED_PARAM_KEYS = {"embed", "router"}
 _GQA_CACHE_KEYS = {"k", "v"}                        # head axis shardable
-_QUANT_SCALE_KEY = "s"                              # head axis is last
 
 
 def _trimmed(spec: List) -> P:
@@ -239,30 +238,36 @@ def serving_param_shardings(params, mesh: Mesh):
 
 
 def serving_cache_spec(path_keys: Tuple[str, ...], shape: Tuple[int, ...],
-                       mesh: Mesh) -> P:
-    """Spec for one paged-arena leaf ``(L, pages, block, [heads], dim)``.
+                       mesh: Mesh, paged: bool = False) -> P:
+    """Spec for one arena leaf: ``paged`` head-major pages ``(L, pages,
+    heads, block, [dim])`` (code and int8-scale planes alike), else
+    per-slot storage ``(L, slots, seq, heads, dim)``.
 
-    Physical pages shard over 'data' (each replica holds its slots'
-    working set); GQA K/V leaves additionally shard the kv-head axis over
-    'model' (axis -2 for value planes, -1 for the per-position quant
-    scale plane). MLA latents (``ckv``/``krope``) carry rank/rope
-    contraction axes, not heads, so they only page-shard."""
+    Physical pages (or slots) shard over 'data' (each replica holds its
+    slots' working set); GQA K/V leaves additionally shard the kv-head
+    axis over 'model' (axis 2 of a page, axis -2 of a slot leaf). MLA
+    latents (``ckv``/``krope``) carry rank/rope contraction axes, not
+    heads, so they only page-shard."""
     spec = [None] * len(shape)
     if len(shape) >= 2 and _serving_fits(shape[1], mesh, "data"):
         spec[1] = "data"
     if any(k in _GQA_CACHE_KEYS for k in path_keys):
-        head_ax = len(shape) - (1 if path_keys[-1] == _QUANT_SCALE_KEY else 2)
+        head_ax = 2 if paged else len(shape) - 2
         if head_ax >= 2 and _serving_fits(shape[head_ax], mesh, "model"):
             spec[head_ax] = "model"
     return _trimmed(spec)
 
 
-def serving_cache_shardings(buffers, mesh: Mesh):
-    """NamedSharding pytree for paged-arena buffers (see serving_cache_spec)."""
-    def spec(path, leaf):
-        keys = tuple(getattr(k, "key", str(k)) for k in path)
-        return NamedSharding(mesh, serving_cache_spec(keys, leaf.shape, mesh))
-    return jax.tree_util.tree_map_with_path(spec, buffers)
+def serving_cache_shardings(buffers, mesh: Mesh, paged_flags=None):
+    """NamedSharding pytree for arena buffers (see serving_cache_spec);
+    ``paged_flags`` marks the flattened leaves that are paged."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(buffers)
+    flags = paged_flags or (False,) * len(flat)
+    out = [NamedSharding(mesh, serving_cache_spec(
+               tuple(getattr(k, "key", str(k)) for k in path), leaf.shape,
+               mesh, paged=f))
+           for (path, leaf), f in zip(flat, flags)]
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def slot_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
@@ -311,6 +316,31 @@ def activation_mesh(mesh: Optional[Mesh]):
         yield
     finally:
         _ACTIVATION_CTX.pop()
+
+
+def traced_serving_mesh() -> Optional[Mesh]:
+    """The serving mesh of the step being traced (innermost
+    ``activation_mesh`` scope), or None outside one."""
+    return _ACTIVATION_CTX[-1] if _ACTIVATION_CTX else None
+
+
+def paged_kernel_specs(mesh: Mesh, batch: int, num_heads: int,
+                       num_kv_heads: int) -> Tuple[P, P, P, P]:
+    """shard_map specs for the fused paged-attention kernel, which Mosaic
+    cannot partition by itself: ``(per-slot (B, C, H, D), pages (NP, Hkv,
+    bs[, D]), tables/positions (B[, MB]), output)``.
+
+    Slots split over 'data' and kv-heads (with their query heads) over
+    'model' where they divide. Pages are whole on every 'data' shard:
+    block tables hold global page ids and the allocator does not keep a
+    slot's pages on its own replica, so a data-sharded arena is gathered
+    for the kernel (reads only; inserts stay GSPMD scatters)."""
+    dp, tp = serving_degrees(mesh)
+    data = "data" if dp > 1 and batch % dp == 0 else None
+    model = "model" if tp > 1 and num_kv_heads % tp == 0 \
+        and num_heads % tp == 0 else None
+    per_slot = P(data, None, model)
+    return per_slot, P(None, model), P(data), per_slot
 
 
 def constrain_replicated(x):

@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import convert
+from repro.kernels import common as kernel_common
 from repro.models.api import ModelAPI
 from repro.parallel import sharding as shard_rules
 from repro.runtime import sampling, speculative
@@ -488,7 +489,11 @@ class ServingEngine:
         self._fresh_arena_sched()
         self._step_compiles = 0
 
-        kw = dict(quant=quant, impl=impl)
+        # One backend decision for every Pallas kernel in the jitted
+        # programs built here (the draft proposer reads the same one):
+        # compiled on a TPU, interpreted only on the CPU.
+        self.interpret = kernel_common.interpret_kernels()
+        kw = dict(quant=quant, impl=impl, interpret=self.interpret)
         self._encode_cross = jax.jit(
             lambda p, f: model.encode_cross(p, f, **kw)) \
             if model.encode_cross is not None else None
@@ -556,6 +561,42 @@ class ServingEngine:
             if spec != "off" else None
 
     # ------------------------------------------------------------------
+    def lower_step(self):
+        """Lower the serving step (the verify step when speculating) at
+        this engine's shapes from abstract operands, without running it:
+        ``lower_step().compile().as_text()`` shows what the backend
+        compiler made of the step — e.g. whether the paged-attention
+        kernel is a TPU custom call."""
+        ns, C = self.num_slots, self.chunk_size
+        i32, f32 = jnp.int32, jnp.float32
+
+        def slot_spec(shape, dtype):
+            sh = None if self.mesh is None else \
+                shard_rules.slot_sharding(self.mesh, len(shape))
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+        def like(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+        args = [jax.tree.map(like, self.params), slot_spec((ns, C), i32),
+                slot_spec((ns,), i32), slot_spec((ns,), i32),
+                slot_spec((ns,), jnp.bool_),
+                jax.tree.map(like, self.arena.buffers),
+                jax.ShapeDtypeStruct((2,), jnp.uint32),
+                slot_spec((ns,), f32),
+                slot_spec((ns,), i32), slot_spec((ns,), f32)]
+        if self.spec != "off":
+            args.insert(4, slot_spec((ns,), i32))
+        if self.paged:
+            args.append(jax.ShapeDtypeStruct(
+                self.arena.tables.shape, i32,
+                sharding=self.arena._table_sharding))
+        if self._vlm:
+            args += [slot_spec((ns, C, self.model.cfg.d_model), jnp.bfloat16),
+                     slot_spec((ns, C), jnp.bool_)]
+        fn = self._step_spec if self.spec != "off" else self._step
+        with shard_rules.activation_mesh(self.mesh):
+            return fn.lower(*args)
+
     def _fresh_arena_sched(self) -> None:
         if self.paged:
             self.arena = PagedKVArena(self.model, self.num_slots,

@@ -11,7 +11,8 @@ prefill, account bytes):
   regardless of actual sequence length.
 
 * ``PagedKVArena`` — paged/block KV allocation. Cache storage becomes
-  (num_blocks, block_size, ...) leaves managed by a ``BlockAllocator``
+  head-major (num_blocks, heads, block_size, feature) leaves managed by
+  a ``BlockAllocator``
   free list; each sequence holds a growable **block table** (logical
   block -> physical block) instead of a contiguous stripe. KV bytes
   resident per sequence shrink from ``max_seq`` to
@@ -498,24 +499,24 @@ def _zero_span(leaves, slot, start, count, width, seq_flags):
 
 @functools.partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
 def _zero_paged_positions(leaves, phys, offs, paged_flags):
-    """Zero (physical page, in-page offset) pairs across the paged leaves
-    (L, NB, bs, ...). Callers pad the pair list to a fixed width with
-    null-block entries — the null page's contents are garbage by the
-    layout contract, so zeroing it is free and keeps one compilation per
-    pad width."""
+    """Zero (physical page, in-page offset) pairs across the head-major
+    paged leaves (L, NB, H, bs, ...), every head at once. Callers pad the
+    pair list to a fixed width with null-block entries — the null page's
+    contents are garbage by the layout contract, so zeroing it is free
+    and keeps one compilation per pad width."""
     out = []
     for a, is_paged in zip(leaves, paged_flags):
         if not is_paged:
             out.append(a)
             continue
-        out.append(a.at[:, phys, offs].set(jnp.zeros((), a.dtype)))
+        out.append(a.at[:, phys, :, offs].set(jnp.zeros((), a.dtype)))
     return out
 
 
 @functools.partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
 def _copy_pages(leaves, src, dst, paged_flags):
     """Copy physical pages ``src[i] -> dst[i]`` across the paged leaves
-    (L, NB, bs, ...) — the copy-on-write split. Callers pad the pair
+    (L, NB, H, bs, ...) — the copy-on-write split. Callers pad the pair
     list to a fixed width with null->null entries (the null page's
     contents are garbage by the layout contract, so self-copying it is
     free) to keep one compilation per pad width."""
@@ -533,10 +534,12 @@ def _paged_insert(buf_leaves, cache_leaves, phys, slot, paged_flags):
     """Scatter a B=1 prefill cache into an arena's physical blocks.
 
     ``buf_leaves``/``cache_leaves``: flattened leaf lists (same treedef).
-    Paged leaves: buffer (L, NB, bs, ...), cache (L, 1, P, ...) — the P
-    prefilled positions are re-blocked and scattered to the ``phys`` block
-    ids (padding past P is garbage-but-masked, exactly like the slot
-    arena's bucket padding; positions past the reservation are dropped).
+    Paged leaves: buffer (L, NB, H, bs, F), cache (L, 1, P, [H,] F) — the
+    P prefilled positions are re-blocked, made head-major (a cache
+    without a head axis, MLA's latents, gets the unit one) and scattered
+    to the ``phys`` block ids (padding past P is garbage-but-masked,
+    exactly like the slot arena's bucket padding; positions past the
+    reservation are dropped).
     Constant leaves: buffer (L, num_slots, ...), written at ``slot``.
     Static ``paged_flags`` keeps one compilation per (cache shape, block
     count) pair — prefill-cache shapes bound the compile count.
@@ -549,8 +552,10 @@ def _paged_insert(buf_leaves, cache_leaves, phys, slot, paged_flags):
             start = (0, slot) + (0,) * (a.ndim - 2)
             out.append(jax.lax.dynamic_update_slice(a, c, start))
             continue
-        bs = a.shape[2]
-        c2 = c[:, 0]                                 # (L, P, ...)
+        bs = a.shape[3]
+        c2 = c[:, 0]                                 # (L, P, [H,] F)
+        if c2.ndim == a.ndim - 2:
+            c2 = c2[:, :, None]                      # (L, P, 1, F)
         want = nbw * bs
         P = c2.shape[1]
         if P < want:
@@ -559,12 +564,13 @@ def _paged_insert(buf_leaves, cache_leaves, phys, slot, paged_flags):
         elif P > want:
             c2 = c2[:, :want]
         c2 = c2.reshape((c2.shape[0], nbw, bs) + c2.shape[2:])
-        out.append(a.at[:, phys].set(c2))
+        out.append(a.at[:, phys].set(jnp.swapaxes(c2, 2, 3)))
     return out
 
 
 class PagedKVArena:
-    """Block-table KV arena: storage is (num_blocks, block_size) pages,
+    """Block-table KV arena: storage is head-major (num_blocks, heads,
+    block_size, feature) pages,
     each slot maps logical blocks to physical blocks through a growable
     table. One extra physical block (id ``num_blocks``) is the **null
     block**: unassigned table entries and inactive slots' writes land
@@ -673,7 +679,8 @@ class PagedKVArena:
             mesh is not None and self.dp > 1 and self.has_paged
             and (num_blocks + 1) % self.dp == 0)
         if mesh is not None:
-            self._shardings = serving_cache_shardings(self.buffers, mesh)
+            self._shardings = serving_cache_shardings(
+                self.buffers, mesh, self._paged_flags)
             self.buffers = jax.device_put(self.buffers, self._shardings)
             if self.dp > 1 and num_slots % self.dp == 0:
                 self._table_sharding = slot_sharding(mesh, 2)
@@ -718,9 +725,13 @@ class PagedKVArena:
         """The page/table layout contract the fused paged-attention
         kernel (``kernels/paged_attention.py``) consumes:
 
-        * paged leaves are ``(num_pages, block_size, ...)`` physical
-          pages with ``num_pages == num_blocks + 1`` — the trailing page
-          (id ``null_block``) is the **null sentinel**;
+        * paged leaves are head-major ``(L, num_pages, heads,
+          block_size, feature)`` physical pages (GQA: kv-heads; MLA
+          latents: a unit head axis) with ``num_pages == num_blocks +
+          1`` — the trailing page (id ``null_block``) is the **null
+          sentinel**. One ``(block_size, feature)`` tile per (page,
+          head) is what the fused kernel DMAs, and its two trailing dims
+          are the array's own, as TPU tiling requires;
         * every slot's block-table row is padded to ``max_blocks``
           entries; entries past the slot's allocation hold
           ``null_block``. Null-page contents are finite garbage (zeros,
@@ -730,7 +741,8 @@ class PagedKVArena:
         * ``kv_quant == "int8"``: each paged leaf is a dict ``{"q", "s"}``
           — int8 code pages in the original page shape plus float16
           scale pages shaped like the pages minus the quantized feature
-          axis (one scale per (in-page position, kv-head)). Codes and
+          axis, ``(L, num_pages, heads, block_size)`` (one scale per
+          (kv-head, in-page position)). Codes and
           scales share the block table; the fused kernel dequantizes
           during the walk and zeroed pages dequantize to exactly zero,
           so the null/rollback/CoW contracts above apply unchanged.

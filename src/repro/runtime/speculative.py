@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import interpret_kernels
 from repro.parallel import sharding as shard_rules
 from repro.runtime.kvcache import KVArena
 from repro.runtime.request import Sequence
@@ -189,7 +190,7 @@ class DraftModelProposer:
         self._depth = [0] * num_slots
         self._tail: List[List[int]] = [[] for _ in range(num_slots)]
 
-        kw = dict(quant=quant, impl=impl)
+        kw = dict(quant=quant, impl=impl, interpret=interpret_kernels())
 
         def pin_cache(arena):
             if mesh is None or self.arena._shardings is None:

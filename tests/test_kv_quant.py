@@ -108,13 +108,16 @@ def _tables(rng, b, mb, nb, null_block, owned=None):
 
 
 def _to_pages(contig, tables, bs, num_pages):
-    pages = np.zeros((num_pages, bs) + contig.shape[2:],
+    """(B, S, H, ...) -> head-major (num_pages, H, bs, ...)."""
+    shape = contig.shape
+    pages = np.zeros((num_pages, shape[2], bs) + shape[3:],
                      np.asarray(contig).dtype)
     for i in range(tables.shape[0]):
         for j in range(tables.shape[1]):
             if tables[i, j] == num_pages - 1:
                 continue
-            pages[tables[i, j]] = np.asarray(contig[i, j * bs:(j + 1) * bs])
+            pages[tables[i, j]] = np.swapaxes(
+                np.asarray(contig[i, j * bs:(j + 1) * bs]), 0, 1)
     return jnp.asarray(pages)
 
 
@@ -184,8 +187,8 @@ def test_gqa_decode_quantized_fused_vs_ref(gqa_model, chunk):
     rng = np.random.RandomState(17)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"k": _quant_pages(k1, (nb + 1, bs, hkv, hd)),
-             "v": _quant_pages(k2, (nb + 1, bs, hkv, hd))}
+    cache = {"k": _quant_pages(k1, (nb + 1, hkv, bs, hd)),
+             "v": _quant_pages(k2, (nb + 1, hkv, bs, hd))}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([5, 9, 2], jnp.int32)
     lengths = jnp.asarray([chunk, max(chunk - 2, 1), chunk], jnp.int32)
@@ -218,8 +221,8 @@ def test_mla_decode_quantized_fused_vs_ref(mla_model, chunk):
     rng = np.random.RandomState(18)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"ckv": _quant_pages(k1, (nb + 1, bs, m.kv_lora_rank)),
-             "krope": _quant_pages(k2, (nb + 1, bs, m.qk_rope_head_dim))}
+    cache = {"ckv": _quant_pages(k1, (nb + 1, 1, bs, m.kv_lora_rank)),
+             "krope": _quant_pages(k2, (nb + 1, 1, bs, m.qk_rope_head_dim))}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([7, 3], jnp.int32)
     lengths = jnp.asarray([chunk, max(chunk - 1, 1)], jnp.int32)
@@ -297,15 +300,16 @@ def _is_qleaf(x):
 
 
 def _logical_values(arena, total, seed):
-    """One random logical (L, total, feat...) tensor per paged dict leaf
-    — same seed => same values, so two arenas fed overlapping position
-    ranges receive identical data on the overlap."""
+    """One random logical (L, total, heads, feat) tensor per paged dict
+    leaf (pages are head-major (L, NB, heads, bs, feat)) — same seed =>
+    same values, so two arenas fed overlapping position ranges receive
+    identical data on the overlap."""
     rngs = [np.random.RandomState(seed + i) for i in range(99)]
     it = iter(rngs)
     return jax.tree.map(
         lambda leaf: next(it).randn(
-            leaf["q"].shape[0], total,
-            *leaf["q"].shape[3:]).astype(np.float32),
+            leaf["q"].shape[0], total, leaf["q"].shape[2],
+            leaf["q"].shape[4]).astype(np.float32),
         arena.buffers, is_leaf=_is_qleaf)
 
 
@@ -325,9 +329,11 @@ def _quant_scatter(arena, slot, data, p0, n):
         if not _is_qleaf(leaf):
             return leaf
         q, s = attn.quantize_kv(jnp.asarray(vals[:, pos]))
-        return {"q": leaf["q"].at[:, phys, offs].set(q),
-                "s": leaf["s"].at[:, phys, offs].set(
-                    s.astype(leaf["s"].dtype))}
+        # index dims (positions) lead the update: (n, L, heads[, feat])
+        return {"q": leaf["q"].at[:, phys, :, offs].set(
+                    jnp.moveaxis(q, 1, 0)),
+                "s": leaf["s"].at[:, phys, :, offs].set(
+                    jnp.moveaxis(s, 1, 0).astype(leaf["s"].dtype))}
     arena.buffers = jax.tree.map(ins, arena.buffers, data,
                                  is_leaf=_is_qleaf)
 

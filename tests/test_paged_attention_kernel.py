@@ -70,16 +70,17 @@ def _tables(rng, b, mb, nb, null_block, owned=None):
 
 
 def _to_pages(contig, tables, bs, num_pages):
-    """(B, S, ...) -> (num_pages, bs, ...) per a block table (null page
-    left zero)."""
-    pages = np.zeros((num_pages, bs) + contig.shape[2:],
+    """(B, S, H, ...) -> head-major (num_pages, H, bs, ...) per a block
+    table (null page left zero)."""
+    shape = contig.shape
+    pages = np.zeros((num_pages, shape[2], bs) + shape[3:],
                      np.asarray(contig).dtype)
     for i in range(tables.shape[0]):
         for j in range(tables.shape[1]):
             if tables[i, j] == num_pages - 1:
                 continue
-            pages[tables[i, j]] = np.asarray(
-                contig[i, j * bs:(j + 1) * bs])
+            pages[tables[i, j]] = np.swapaxes(
+                np.asarray(contig[i, j * bs:(j + 1) * bs]), 0, 1)
     return jnp.asarray(pages)
 
 
@@ -167,8 +168,8 @@ def test_gqa_decode_fused_vs_ref_layer(gqa_model, chunk):
     rng = np.random.RandomState(7)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"k": jax.random.normal(k1, (nb + 1, bs, hkv, hd), jnp.float32),
-             "v": jax.random.normal(k2, (nb + 1, bs, hkv, hd), jnp.float32)}
+    cache = {"k": jax.random.normal(k1, (nb + 1, hkv, bs, hd), jnp.float32),
+             "v": jax.random.normal(k2, (nb + 1, hkv, bs, hd), jnp.float32)}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([5, 9, 2], jnp.int32)
     lengths = jnp.asarray([chunk, max(chunk - 2, 1), chunk], jnp.int32)
@@ -201,9 +202,10 @@ def test_mla_decode_fused_vs_ref_layer(mla_model, chunk):
     rng = np.random.RandomState(8)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"ckv": jax.random.normal(k1, (nb + 1, bs, m.kv_lora_rank),
+    cache = {"ckv": jax.random.normal(k1, (nb + 1, 1, bs, m.kv_lora_rank),
                                       jnp.float32),
-             "krope": jax.random.normal(k2, (nb + 1, bs, m.qk_rope_head_dim),
+             "krope": jax.random.normal(k2,
+                                        (nb + 1, 1, bs, m.qk_rope_head_dim),
                                         jnp.float32)}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([7, 3], jnp.int32)

@@ -171,9 +171,11 @@ def test_paged_arena_lifecycle(gqa_model):
     arena.free_slot(s1)
     assert arena.allocator.free_blocks == 3
     assert (arena.tables[s1] == arena.null_block).all()
-    # storage: paged leaves are (L, NB+1, bs, ...), per-slot leaves absent
+    # storage: paged leaves are head-major (L, NB+1, Hkv, bs, D),
+    # per-slot leaves absent
     leaf = jax.tree.leaves(arena.buffers)[0]
-    assert leaf.shape[1] == 7 and leaf.shape[2] == 4
+    assert leaf.shape[1] == 7 and leaf.shape[2] == cfg.num_kv_heads
+    assert leaf.shape[3] == 4
     assert arena.block_bytes() > 0
     assert arena.resident_bytes() == pytest.approx(
         arena.allocator.used_blocks * arena.block_bytes())
@@ -186,7 +188,7 @@ def test_paged_write_prefill_lands_in_reserved_blocks(gqa_model):
     _, cache = model.prefill(params, {"tokens": jnp.ones((1, 8), jnp.int32)})
     before = jax.tree.leaves(arena.buffers)[0].copy()
     arena.write_prefill(cache, slot)
-    leaf = jax.tree.leaves(arena.buffers)[0]      # (L, NB+1, bs, H, D)
+    leaf = jax.tree.leaves(arena.buffers)[0]      # (L, NB+1, H, bs, D)
     phys = arena.slot_blocks(slot)
     for b in phys:
         assert not bool(jnp.array_equal(leaf[:, b], before[:, b]))
@@ -264,11 +266,17 @@ def test_request_rejects_degenerate_prompts():
 # Differential: paged decode == contiguous decode (GQA + MLA)
 # ----------------------------------------------------------------------
 def _scatter_to_pages(contig, tables, bs, nb):
-    """(B, S, ...) -> (NB+1, bs, ...) pages per a (B, MB) block table."""
-    pages = np.zeros((nb + 1, bs) + contig.shape[2:], contig.dtype)
+    """(B, S, H, ...) -> head-major (NB+1, H, bs, ...) pages per a
+    (B, MB) block table; a cache without a head axis (MLA latents) gets
+    the unit one."""
+    if contig.ndim == 3:
+        contig = contig[:, :, None]
+    pages = np.zeros((nb + 1, contig.shape[2], bs) + contig.shape[3:],
+                     contig.dtype)
     for i in range(contig.shape[0]):
         for j in range(tables.shape[1]):
-            pages[tables[i, j]] = np.asarray(contig[i, j * bs:(j + 1) * bs])
+            pages[tables[i, j]] = np.swapaxes(
+                np.asarray(contig[i, j * bs:(j + 1) * bs]), 0, 1)
     return jnp.asarray(pages)
 
 

@@ -72,25 +72,31 @@ def test_weight_spec_replicates_indivisible_out_axis():
 
 def test_cache_spec_gqa_heads_and_pages():
     m = FakeMesh(2, 2)
-    # (L, pages, block, kv_heads, hd): pages over 'data', heads over
-    # 'model'; the trailing feature axis is never sharded.
+    # head-major (L, pages, kv_heads, block, hd): pages over 'data',
+    # heads over 'model'; the trailing feature axis is never sharded.
+    assert shard_rules.serving_cache_spec(("k",), (4, 20, 2, 8, 32), m,
+                                          paged=True) \
+        == P(None, "data", "model")
+    # int8 scale plane (L, pages, kv_heads, block): same head axis.
+    assert shard_rules.serving_cache_spec(("k", "s"), (4, 20, 2, 8), m,
+                                          paged=True) \
+        == P(None, "data", "model")
+    # Indivisible page count: pages replicate, heads still shard.
+    assert shard_rules.serving_cache_spec(("k",), (4, 21, 2, 8, 32), m,
+                                          paged=True) \
+        == P(None, None, "model")
+    # Per-slot storage (L, slots, seq, kv_heads, hd): head axis is -2.
     assert shard_rules.serving_cache_spec(("k",), (4, 20, 8, 2, 32), m) \
         == P(None, "data", None, "model")
-    # int8 scale plane (L, pages, block, kv_heads): head axis is last.
-    assert shard_rules.serving_cache_spec(("k", "s"), (4, 20, 8, 2), m) \
-        == P(None, "data", None, "model")
-    # Indivisible page count: pages replicate, heads still shard.
-    assert shard_rules.serving_cache_spec(("k",), (4, 21, 8, 2, 32), m) \
-        == P(None, None, None, "model")
 
 
 def test_cache_spec_mla_latents_page_shard_only():
     m = FakeMesh(2, 2)
     # ckv/krope trailing axes are contraction dims — replicated.
-    assert shard_rules.serving_cache_spec(("ckv",), (4, 20, 8, 64), m) \
-        == P(None, "data")
-    assert shard_rules.serving_cache_spec(("krope",), (4, 20, 8, 16), m) \
-        == P(None, "data")
+    assert shard_rules.serving_cache_spec(("ckv",), (4, 20, 1, 8, 64), m,
+                                          paged=True) == P(None, "data")
+    assert shard_rules.serving_cache_spec(("krope",), (4, 20, 1, 8, 16), m,
+                                          paged=True) == P(None, "data")
 
 
 def test_specs_never_name_size_one_axes():
@@ -99,13 +105,13 @@ def test_specs_never_name_size_one_axes():
     on committed inputs doubles the executable cache (compiles == 2)."""
     m = FakeMesh(1, 1)
     assert shard_rules.serving_weight_spec(("w",), (64, 32), m) == P()
-    assert shard_rules.serving_cache_spec(("k",), (4, 20, 8, 2, 32), m) \
-        == P()
+    assert shard_rules.serving_cache_spec(("k",), (4, 20, 2, 8, 32), m,
+                                          paged=True) == P()
     assert shard_rules.slot_sharding(mesh_1x1(), 3).spec == P()
     # dp=1, tp=2: only the head axis is named, pages stay unnamed.
     m = FakeMesh(1, 2)
-    assert shard_rules.serving_cache_spec(("k",), (4, 20, 8, 2, 32), m) \
-        == P(None, None, None, "model")
+    assert shard_rules.serving_cache_spec(("k",), (4, 20, 2, 8, 32), m,
+                                          paged=True) == P(None, None, "model")
 
 
 def test_specs_never_carry_trailing_none():
@@ -114,8 +120,10 @@ def test_specs_never_carry_trailing_none():
     m = FakeMesh(2, 2)
     for spec in (
             shard_rules.serving_weight_spec(("w",), (64, 32), m),
-            shard_rules.serving_cache_spec(("k",), (4, 20, 8, 2, 32), m),
-            shard_rules.serving_cache_spec(("ckv",), (4, 20, 8, 64), m)):
+            shard_rules.serving_cache_spec(("k",), (4, 20, 2, 8, 32), m,
+                                           paged=True),
+            shard_rules.serving_cache_spec(("ckv",), (4, 20, 1, 8, 64), m,
+                                           paged=True)):
         assert len(spec) == 0 or spec[-1] is not None
 
 
