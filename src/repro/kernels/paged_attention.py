@@ -303,6 +303,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, cg, dv),
                                        out_dtype or q.dtype),
+        name="paged_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,

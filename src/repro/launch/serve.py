@@ -203,7 +203,7 @@ def run_stream(cfg, model, params, args) -> None:
           f"({args.arrival}) {arena_desc} "
           f"prefill=chunked[{engine.chunk_size}] gen={args.gen}")
     # ONE formatter emits every report line (scheduler, arena, spec,
-    # prefix, timing, percentiles, mesh, ledger, bottleneck) — the
+    # prefix, timing, percentiles, mesh, ledger, host phases) — the
     # hand-rolled print block and TransferReport.summary_lines used to
     # drift apart; see telemetry.serve_report_lines.
     for line in serve_report_lines(engine, report,

@@ -417,14 +417,19 @@ def lm_decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
 
     ``embeds``/``embeds_mask``: (B, C, d) / (B, C) — vlm chunked prefill:
     positions flagged in the mask take the provided embedding (the stub
-    vision patches) instead of the token embedding."""
+    vision patches) instead of the token embedding.
+
+    The step's parts are named scopes (``embed``, ``layers``,
+    ``lm_head``) in every op's metadata, so a device trace's ops map to
+    the part of the model they run; names change no op."""
     recipe = layers.recipe_for(quant)
     fmt = recipe["linear"]
     b, cw = token.shape
-    h = layers.embedding_lookup(params["embed"], token, recipe["embed"],
-                                jnp.bfloat16, width=cfg.d_model)
-    if embeds is not None:
-        h = jnp.where(embeds_mask[..., None], embeds.astype(h.dtype), h)
+    with jax.named_scope("embed"):
+        h = layers.embedding_lookup(params["embed"], token, recipe["embed"],
+                                    jnp.bfloat16, width=cfg.d_model)
+        if embeds is not None:
+            h = jnp.where(embeds_mask[..., None], embeds.astype(h.dtype), h)
     mrope_pos = None
     if cfg.mrope:
         pos_mat = attn.decode_positions(position, b, cw)
@@ -453,11 +458,13 @@ def lm_decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
                                          paged_impl=paged_impl)
                     c[f"sub{i}"] = ci
             return h, c
-        h, new_cache = jax.lax.scan(body, h, (params[name], cache[name]),
-                                     unroll=flags.inner_unroll())
+        with jax.named_scope("layers"):     # the body is traced inside
+            h, new_cache = jax.lax.scan(body, h, (params[name], cache[name]),
+                                         unroll=flags.inner_unroll())
         new_caches[name] = new_cache
-    h = layers.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
-    logits = _lm_head(params, cfg, h, quant, impl, interpret)
+    with jax.named_scope("lm_head"):
+        h = layers.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
+        logits = _lm_head(params, cfg, h, quant, impl, interpret)
     return logits, new_caches
 
 

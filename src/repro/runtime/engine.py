@@ -75,7 +75,7 @@ from repro.runtime import sampling, speculative
 from repro.runtime.kvcache import KV_QUANT_MODES, KVArena, PagedKVArena
 from repro.runtime.request import Request, SamplingParams, SeqState, Sequence
 from repro.runtime.scheduler import Scheduler, SchedulerStats
-from repro.runtime.telemetry import StepTimeline
+from repro.runtime.telemetry import StepTimeline, span
 from repro.runtime.transfers import TransferLedger, TransferReport
 
 
@@ -531,11 +531,12 @@ class ServingEngine:
             logits, arena = model_pass(p, tokens, pos0, lengths, arena,
                                        rest)
             arena = pin_cache(arena)
-            idx = jnp.maximum(lengths - 1, 0)
-            last = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1)[:, 0]
-            nxt = sampling.sample_slots(last, key, temps, active,
-                                        top_k=top_ks, top_p=top_ps)
+            with jax.named_scope("sample"):
+                idx = jnp.maximum(lengths - 1, 0)
+                last = jnp.take_along_axis(
+                    logits, idx[:, None, None], axis=1)[:, 0]
+                nxt = sampling.sample_slots(last, key, temps, active,
+                                            top_k=top_ks, top_p=top_ps)
             return nxt, arena
         self._step = jax.jit(step,
                              donate_argnums=(5,) if donate_cache else ())
@@ -551,10 +552,11 @@ class ServingEngine:
             logits, arena = model_pass(p, tokens, pos0, lengths, arena,
                                        rest)
             arena = pin_cache(arena)
-            nxt, acc = sampling.verify_slots(
-                logits, tokens, key, temps, active,
-                prop_lens=prop_lens, lengths=lengths,
-                top_k=top_ks, top_p=top_ps)
+            with jax.named_scope("sample"):
+                nxt, acc = sampling.verify_slots(
+                    logits, tokens, key, temps, active,
+                    prop_lens=prop_lens, lengths=lengths,
+                    top_k=top_ks, top_p=top_ps)
             return nxt, acc, arena
         self._step_spec = jax.jit(
             spec_step, donate_argnums=(6,) if donate_cache else ()) \
@@ -802,119 +804,143 @@ class ServingEngine:
                 nbytes += (hi - lo) * d * 2               # bf16 upload
         return embeds, mask, nbytes
 
-    def _step_once(self, key, stats: GenStats, ledger: TransferLedger,
-                   t0: float) -> None:
-        """One unified (slots, chunk) step: prompt chunks and decode
-        feedback tokens ride the same traced shape. Token timestamps are
-        read *after* the step's host sync so TTFT/latency include the step
-        (and any first-step compile) that produced each token."""
+    def _plan_feeds(self):
+        """This step's feed per active slot (``Scheduler.plan_feeds``)
+        and, when speculating, the proposals for the budget-granted
+        lanes: ``(feeds, proposals)``."""
+        C = self.chunk_size
+        proposals: Dict[int, np.ndarray] = {}
+        if self.spec == "off":
+            return self.sched.plan_feeds(C, self.step_token_budget), \
+                proposals
+        desires = {slot: d for slot, seq in self.sched.active.items()
+                   if seq.state is SeqState.DECODE
+                   and (d := self._spec_depth(seq)) > 0}
+        feeds = self.sched.plan_feeds(C, self.step_token_budget, desires)
+        # Propose only the budget-granted lanes (the draft proposer pays
+        # real steps per lane; the n-gram proposer may return fewer than
+        # granted — or nothing — when no suffix matches).
+        grants = {s: feeds[s] - 1 for s in desires if feeds[s] > 1}
+        if grants:
+            proposals = self._proposer.propose(self.sched.active, grants)
+            for slot, g in grants.items():
+                got = proposals.get(slot)
+                unfilled = g - (0 if got is None else int(got.size))
+                if unfilled > 0:
+                    # Lanes the proposer could not fill are zero-value
+                    # evidence: decay the depth EMA so a slot with no
+                    # matchable suffix stops reserving speculative paged
+                    # blocks it never uses (depth floors at 1).
+                    self._spec_ctrl.update(slot, unfilled, 0)
+        return feeds, proposals
+
+    def _step_once(self, key, feeds: Dict[int, int],
+                   proposals: Dict[int, np.ndarray], stats: GenStats,
+                   ledger: TransferLedger, t0: float) -> None:
+        """One unified (slots, chunk) step over the planned ``feeds``:
+        prompt chunks and decode feedback tokens ride the same traced
+        shape. Its host phases are spans (``serve.operands``,
+        ``serve.dispatch``, ``serve.sync``, ``serve.bookkeep``); the
+        step's ``t_start``/``t_end`` are the dispatch's start and the
+        sync's end. Token timestamps are read *after* the step's host
+        sync so TTFT/latency include the step (and any first-step
+        compile) that produced each token."""
         ns, C = self.num_slots, self.chunk_size
         spec_on = self.spec != "off"
-        proposals: Dict[int, np.ndarray] = {}
-        if spec_on:
-            desires = {slot: d for slot, seq in self.sched.active.items()
-                       if seq.state is SeqState.DECODE
-                       and (d := self._spec_depth(seq)) > 0}
-            feeds = self.sched.plan_feeds(C, self.step_token_budget,
-                                          desires)
-            # Propose only the budget-granted lanes (the draft proposer
-            # pays real steps per lane; the n-gram proposer may return
-            # fewer than granted — or nothing — when no suffix matches).
-            grants = {s: feeds[s] - 1 for s in desires if feeds[s] > 1}
-            if grants:
-                proposals = self._proposer.propose(self.sched.active,
-                                                   grants)
-                for slot, g in grants.items():
-                    got = proposals.get(slot)
-                    unfilled = g - (0 if got is None else int(got.size))
-                    if unfilled > 0:
-                        # Lanes the proposer could not fill are zero-value
-                        # evidence: decay the depth EMA so a slot with no
-                        # matchable suffix stops reserving speculative
-                        # paged blocks it never uses (depth floors at 1).
-                        self._spec_ctrl.update(slot, unfilled, 0)
-        else:
-            feeds = self.sched.plan_feeds(C, self.step_token_budget)
-        tokens = np.zeros((ns, C), np.int32)
-        pos0 = np.zeros((ns,), np.int32)
-        lens = np.zeros((ns,), np.int32)
-        prop_lens = np.zeros((ns,), np.int32)
-        active = np.zeros((ns,), bool)
-        for slot, seq in self.sched.active.items():
-            n = feeds[slot]
-            if seq.state is SeqState.PREFILL:
-                tokens[slot, :n] = seq.req.tokens[seq.fed:seq.fed + n]
-            else:
-                tokens[slot, 0] = seq.next_token
-                props = proposals.get(slot)
-                if props is not None and props.size:
-                    kp = min(int(props.size), n - 1)
-                    tokens[slot, 1:1 + kp] = props[:kp]
-                    prop_lens[slot] = kp
-                n = 1 + int(prop_lens[slot])  # actual feed may undershoot
-                feeds[slot] = n               # the plan (lanes unfilled)
-            pos0[slot] = seq.position
-            lens[slot] = n
-            active[slot] = True
-        temps, top_ks, top_ps = self._sampling_vectors(self.sched.active)
+        tl = self._timeline
+        with span("serve.operands", tl):
+            tokens = np.zeros((ns, C), np.int32)
+            pos0 = np.zeros((ns,), np.int32)
+            lens = np.zeros((ns,), np.int32)
+            prop_lens = np.zeros((ns,), np.int32)
+            active = np.zeros((ns,), bool)
+            for slot, seq in self.sched.active.items():
+                n = feeds[slot]
+                if seq.state is SeqState.PREFILL:
+                    tokens[slot, :n] = seq.req.tokens[seq.fed:seq.fed + n]
+                else:
+                    tokens[slot, 0] = seq.next_token
+                    props = proposals.get(slot)
+                    if props is not None and props.size:
+                        kp = min(int(props.size), n - 1)
+                        tokens[slot, 1:1 + kp] = props[:kp]
+                        prop_lens[slot] = kp
+                    n = 1 + int(prop_lens[slot])  # actual feed may undershoot
+                    feeds[slot] = n               # the plan (lanes unfilled)
+                pos0[slot] = seq.position
+                lens[slot] = n
+                active[slot] = True
+            temps, top_ks, top_ps = self._sampling_vectors(self.sched.active)
 
-        t_step = time.perf_counter()
-        before = self._jit_cache_size()
-        if self.mesh is None:
-            put = jnp.asarray
-        else:
-            # Commit per-slot operands with the slot axis over 'data' so
-            # GSPMD partitions the step along slots without a gather.
-            def put(a):
-                a = np.asarray(a)
-                return jax.device_put(
-                    a, shard_rules.slot_sharding(self.mesh, a.ndim))
-        step_args = [self.params, put(tokens), put(pos0),
-                     put(lens), put(active),
-                     self.arena.buffers, key, put(temps),
-                     put(top_ks), put(top_ps)]
-        if spec_on:
-            step_args.insert(4, put(prop_lens))
-        if self.paged:
-            dev_tables, uploaded = self.arena.device_tables()
-            step_args.append(dev_tables)
-            if uploaded:        # dirty tables only: admission/growth/preempt
-                ledger.charge("decode", "tables", "h2d", uploaded)
-        if self._vlm:
-            embeds, emask, vis_bytes = self._vision_override(feeds)
-            step_args += [jnp.asarray(embeds, jnp.bfloat16),
-                          jnp.asarray(emask)]
-            if vis_bytes:
-                ledger.charge("prefill", "acts", "h2d", vis_bytes)
-        # The scope makes the MoE token-path replication pin live during
-        # the step *trace* (first call only; later calls hit the jit
-        # cache). Committed input shardings carry everything else — see
-        # parallel/sharding.py for why no other in-graph constraint may
-        # appear (each one perturbs fusion and hence bf16 rounding).
-        with shard_rules.activation_mesh(self.mesh):
-            if spec_on:
-                # The verify step IS the chunked step with the
-                # verification sampling head; spec engines run it
-                # exclusively (zero proposals degenerate to plain
-                # sampling), so the jit cache still holds exactly one
-                # step compilation.
-                nxt, acc, self.arena.buffers = self._step_spec(*step_args)
+        with span("serve.dispatch", tl) as dispatch:
+            before = self._jit_cache_size()
+            if self.mesh is None:
+                put = jnp.asarray
             else:
-                nxt, self.arena.buffers = self._step(*step_args)
-                acc = None
-        acc_host = np.asarray(acc) if acc is not None else None
-        nxt_host = np.asarray(nxt)            # blocks until step completes
-        t_end = time.perf_counter()
+                # Commit per-slot operands with the slot axis over 'data' so
+                # GSPMD partitions the step along slots without a gather.
+                def put(a):
+                    a = np.asarray(a)
+                    return jax.device_put(
+                        a, shard_rules.slot_sharding(self.mesh, a.ndim))
+            step_args = [self.params, put(tokens), put(pos0),
+                         put(lens), put(active),
+                         self.arena.buffers, key, put(temps),
+                         put(top_ks), put(top_ps)]
+            if spec_on:
+                step_args.insert(4, put(prop_lens))
+            if self.paged:
+                dev_tables, uploaded = self.arena.device_tables()
+                step_args.append(dev_tables)
+                if uploaded:    # dirty tables only: admission/growth/preempt
+                    ledger.charge("decode", "tables", "h2d", uploaded)
+            if self._vlm:
+                embeds, emask, vis_bytes = self._vision_override(feeds)
+                step_args += [jnp.asarray(embeds, jnp.bfloat16),
+                              jnp.asarray(emask)]
+                if vis_bytes:
+                    ledger.charge("prefill", "acts", "h2d", vis_bytes)
+            # The scope makes the MoE token-path replication pin live during
+            # the step *trace* (first call only; later calls hit the jit
+            # cache). Committed input shardings carry everything else — see
+            # parallel/sharding.py for why no other in-graph constraint may
+            # appear (each one perturbs fusion and hence bf16 rounding).
+            with shard_rules.activation_mesh(self.mesh):
+                if spec_on:
+                    # The verify step IS the chunked step with the
+                    # verification sampling head; spec engines run it
+                    # exclusively (zero proposals degenerate to plain
+                    # sampling), so the jit cache still holds exactly one
+                    # step compilation.
+                    nxt, acc, self.arena.buffers = self._step_spec(*step_args)
+                else:
+                    nxt, self.arena.buffers = self._step(*step_args)
+                    acc = None
+        with span("serve.sync", tl) as sync:
+            acc_host = np.asarray(acc) if acc is not None else None
+            nxt_host = np.asarray(nxt)        # blocks until step completes
+        t_step, t_end = dispatch.t0, sync.t1
         now = t_end - t0
+        with span("serve.bookkeep", tl):
+            self._bookkeep(feeds, tokens, pos0, lens, prop_lens, nxt_host,
+                           acc_host, before, t_step - t0, now, stats,
+                           ledger)
+
+    def _bookkeep(self, feeds, tokens, pos0, lens, prop_lens, nxt_host,
+                  acc_host, before: int, t_start: float, now: float,
+                  stats: GenStats, ledger: TransferLedger) -> None:
+        """Everything a step leaves to the host after its sync: compile
+        count, time split, ledger charges, resident bytes, the paged-read
+        count, token records, the step event and slot retirement."""
+        ns, C = self.num_slots, self.chunk_size
         dcomp = self._jit_cache_size() - before
         self._step_compiles += dcomp
-
         pre_toks = sum(n for s, n in feeds.items()
                        if self.sched.active[s].state is SeqState.PREFILL)
         dec_toks = sum(n for s, n in feeds.items()
                        if self.sched.active[s].state is SeqState.DECODE)
         frac = pre_toks / max(pre_toks + dec_toks, 1)
-        dt = t_end - t_step
+        dt = now - t_start
         stats.prefill_s += dt * frac
         stats.decode_s += dt * (1.0 - frac)
         ledger.charge_step_weights(prefill_frac=frac)
@@ -1053,7 +1079,7 @@ class ServingEngine:
                 counters["draft_steps"] = float(
                     getattr(self._proposer, "steps", 0))
             tl.record_step(
-                t_start=t_step - t0, t_end=now,
+                t_start=t_start, t_end=now,
                 occupancy=len(self.sched.active), compiles=dcomp,
                 counters=counters,
                 gauges={"resident_bytes": float(resident),
@@ -1126,33 +1152,50 @@ class ServingEngine:
             self.sched.telemetry = tl
         key = jax.random.PRNGKey(seed)
         t0 = time.perf_counter()
+        if tl is not None:
+            tl.origin = t0
 
+        # Each iteration is one profiler step (numbered by the step it
+        # runs, or would run next) tiled by disjoint host-phase spans:
+        # serve.schedule here, then serve.operands, serve.dispatch,
+        # serve.sync and serve.bookkeep in _step_once — or serve.wait
+        # when no slot is active.
         while self.sched.has_work:
-            now = time.perf_counter() - t0
-            if self.paged:
-                # Incumbents reserve their next-feed blocks BEFORE new
-                # admissions take them (may preempt-to-queue): admitting
-                # first could burn ingestion work on a sequence that the
-                # very next reserve pass would evict. A fresh admission's
-                # first feed is covered by its own admission reservation,
-                # so skipping it here is safe.
-                self._reserve_blocks(ledger)
-            admitted = self.sched.admit(self._try_admit, now)
-            for seq in admitted:
-                self._admit_chunked(seq, stats, ledger)
-            if not self.sched.active:
+            with jax.profiler.StepTraceAnnotation("serve_step",
+                                                  step_num=stats.steps):
+                if tl is not None:
+                    tl.open_iteration()
+                with span("serve.schedule", tl):
+                    now = time.perf_counter() - t0
+                    if self.paged:
+                        # Incumbents reserve their next-feed blocks BEFORE
+                        # new admissions take them (may preempt-to-queue):
+                        # admitting first could burn ingestion work on a
+                        # sequence that the very next reserve pass would
+                        # evict. A fresh admission's first feed is covered
+                        # by its own admission reservation, so skipping it
+                        # here is safe.
+                        self._reserve_blocks(ledger)
+                    admitted = self.sched.admit(self._try_admit, now)
+                    for seq in admitted:
+                        self._admit_chunked(seq, stats, ledger)
+                    if self.sched.active:
+                        key, sub = jax.random.split(key)
+                        feeds, proposals = self._plan_feeds()
+                if self.sched.active:
+                    self._step_once(sub, feeds, proposals, stats, ledger,
+                                    t0)
+                    continue
                 if self.sched.queue:
                     continue    # preempted/starved: blocks freed, re-admit
                 nxt = self.sched.next_arrival()
                 if nxt is None:
                     break               # queued-but-no-slot cannot happen here
-                if realtime:
-                    time.sleep(min(max(nxt - now, 0.0), 0.05))
-                else:
-                    self.sched.poll_arrivals(float("inf"))
-                continue
-            key, sub = jax.random.split(key)
-            self._step_once(sub, stats, ledger, t0)
+                with span("serve.wait", tl):
+                    if realtime:
+                        time.sleep(min(max(nxt - now, 0.0), 0.05))
+                    else:
+                        self.sched.poll_arrivals(float("inf"))
 
         if tl is not None:
             tl.finalize(time.perf_counter() - t0)

@@ -1,40 +1,40 @@
-"""Per-step serving telemetry: step timeline, streaming percentiles,
-Perfetto trace export, and transfer-bottleneck attribution.
+"""Per-step serving telemetry: host phase spans, step timeline,
+streaming percentiles and the Perfetto trace export.
 
-The paper's system-level finding (§V.A) is that host<->accelerator data
-transfer — not kernel execution — bounds LLM inference on the CGLA. The
-``TransferLedger`` counts those bytes; this module shows *when* they
-land, how transfer interleaves with compute, and which phase dominates a
-live serve, so every perf claim ships with a per-step evidence trail
-instead of two summary numbers. Zero dependencies beyond numpy, strictly
-host-side: telemetry never touches a traced value, so ``step_compiles``
-and the emitted tokens are identical with it on or off (pinned in
-tests/test_telemetry.py and gated in bench_serving.py).
+Every claim about where a serving step's time goes ships with a per-step
+evidence trail: one structured event per engine step, and the host
+phases of the serving loop (``span``) recorded on that event and, as
+``jax.profiler.TraceAnnotation``s, on the profiler's clock beside the
+device's operations. Strictly host-side: telemetry never touches a
+traced value, so ``step_compiles`` and the emitted tokens are identical
+with it on or off (pinned in tests/test_telemetry.py and gated in
+bench_serving.py).
 
 Pieces:
 
-* ``LogHistogram`` — fixed-bin log histogram: a mergeable streaming
-  percentile estimator with bounded relative error (one bin width),
-  replacing ad-hoc latency lists. Used for TTFT, inter-token latency,
-  queue wait, request latency and step wall-clock.
+* ``span`` — the one span mechanism: a context manager that annotates
+  the profiler trace and, given a timeline, records ``(t0, t1)`` on the
+  current step's event. The engine wraps each serve-loop iteration in a
+  ``jax.profiler.StepTraceAnnotation`` and splits it into the disjoint
+  phases of ``SERVE_PHASES`` (plus ``serve.wait`` when idle).
+* ``LogHistogram`` — log-binned histogram on one geometric grid: a
+  mergeable streaming percentile estimator with bounded relative error
+  (one bin width) for every positive value. Used for TTFT, inter-token
+  latency, queue wait, request latency and step wall-clock.
 * ``StepEvent`` / ``StepTimeline`` — one structured event per engine
-  step: phase mix per slot, occupancy, wall-clock, jit-compile events,
-  preemptions, speculative and prefix-sharing counter deltas, and the
-  *delta* of every TransferLedger (phase, category, direction) cell.
-  Deltas are captured through the ledger's charge tap (see
-  ``TransferLedger.attach_tap``), so the timeline's accumulated cells
-  close bit-exactly against ``ledger.breakdown()`` — every charge path
-  (admission growth, preemption, rollback, prefix hits, draft account)
-  flows through the same tap.
+  step: phase mix per slot, occupancy, wall-clock, host phase spans,
+  jit-compile events, preemptions, speculative and prefix-sharing
+  counter deltas, and the *delta* of every TransferLedger (phase,
+  category, direction) cell. Deltas are captured through the ledger's
+  charge tap (see ``TransferLedger.attach_tap``), so the timeline's
+  accumulated cells close bit-exactly against ``ledger.breakdown()`` —
+  every charge path (admission growth, preemption, rollback, prefix
+  hits, draft account) flows through the same tap.
 * Exporters — a JSONL metrics sink (schema below, validated by
   ``validate_metrics_jsonl``) and a Chrome-trace/Perfetto JSON export
-  (``write_chrome_trace``): steps as spans on per-slot tracks plus
-  ledger byte counter tracks, droppable into https://ui.perfetto.dev.
-* ``BottleneckReport`` — per-step transfer-bound vs compute-bound
-  attribution: the modeled DMA time of the step's delta bytes
-  (``TransferModel``, the bench's LOAD model) against the measured step
-  wall-clock (EXEC) — the paper's LOAD-vs-EXEC analysis reproduced from
-  live runs, with per-device figures under ``--dp``/``--tp``.
+  (``write_chrome_trace``): steps and their host phases on the engine
+  track, per-slot tracks, ledger byte counter tracks, droppable into
+  https://ui.perfetto.dev.
 * ``serve_report_lines`` — the ONE formatter behind serve.py's report
   and the ledger/spec/prefix/per-device summary, so the two report
   paths cannot drift.
@@ -46,22 +46,30 @@ see docs/observability.md for the full field glossary):
   admit    {rid, t, queue_wait_s}
   preempt  {rid, t}
   step     {step, t_start, t_end, wall_s, occupancy, compiles,
-            counters, gauges, slots, ledger_delta, draft_delta,
-            load_s, bound}
-  summary  {steps, histograms, percentiles, bottleneck, ledger_total}
+            counters, gauges, slots, ledger_delta, draft_delta, spans}
+  summary  {steps, histograms, percentiles, ledger_total}
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+import time
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
-from repro.core.coalesce import TransferModel
-from repro.runtime.transfers import (D2H, H2D, PHASES, TransferLedger)
+import jax
+
+from repro.runtime.transfers import D2H, H2D, TransferLedger
 
 # Cell key: (phase, category, direction) — the ledger's grid flattened.
 CellKey = Tuple[str, str, str]
+
+#: The disjoint host phases of one serve-loop iteration that runs a
+#: step, in order. ``serve.dispatch`` starts at the step's ``t_start``
+#: and ``serve.sync`` ends at its ``t_end``.
+SERVE_PHASES = ("serve.schedule", "serve.operands", "serve.dispatch",
+                "serve.sync", "serve.bookkeep")
 
 #: JSONL event types and the keys every instance must carry.
 METRICS_SCHEMA: Dict[str, Tuple[str, ...]] = {
@@ -70,9 +78,8 @@ METRICS_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "preempt": ("rid", "t"),
     "step": ("step", "t_start", "t_end", "wall_s", "occupancy",
              "compiles", "counters", "gauges", "slots", "ledger_delta",
-             "load_s", "bound"),
-    "summary": ("steps", "histograms", "percentiles", "bottleneck",
-                "ledger_total"),
+             "spans"),
+    "summary": ("steps", "histograms", "percentiles", "ledger_total"),
 }
 
 
@@ -81,24 +88,59 @@ def _cell_str(key: CellKey) -> str:
     return "/".join(key)
 
 
+class Span:
+    """One host phase as ``span`` measured it: ``[t0, t1)`` in
+    ``time.perf_counter`` seconds (``t1`` is set on exit)."""
+
+    __slots__ = ("t0", "t1")
+
+    def __init__(self):
+        self.t0 = self.t1 = 0.0
+
+
+@contextlib.contextmanager
+def span(name: str, timeline: Optional["StepTimeline"] = None
+         ) -> Iterator[Span]:
+    """Time one host phase of the serving loop.
+
+    The phase enters ``jax.profiler.TraceAnnotation(name)``, so while a
+    profiler trace runs it lands on the trace's clock in the host plane,
+    beside the device's operations. With a ``timeline`` it is also
+    recorded on the timeline's current step (``StepTimeline.add_span``).
+    Yields the ``Span``, whose ``t0``/``t1`` are the phase's own stamps.
+    """
+    rec = Span()
+    with jax.profiler.TraceAnnotation(name):
+        rec.t0 = time.perf_counter()
+        try:
+            yield rec
+        finally:
+            rec.t1 = time.perf_counter()
+    if timeline is not None:
+        timeline.add_span(name, rec.t0, rec.t1)
+
+
 class LogHistogram:
-    """Fixed-bin log-spaced histogram: a mergeable streaming quantile
-    estimator.
+    """Log-binned histogram: a mergeable streaming quantile estimator.
 
-    Values land in geometrically spaced bins between ``lo`` and ``hi``
-    (``bins_per_decade`` per factor of 10), so a percentile estimate is
-    off by at most one bin width — a bounded *relative* error of
-    ``10**(1/bins_per_decade) - 1`` (~10% at the default 24/decade,
-    halved in expectation by the geometric-midpoint readout) regardless
-    of how many samples stream through. Constant memory, O(1) record,
-    mergeable across histograms with identical bin geometry (shard-local
-    telemetry can be reduced without keeping raw samples).
+    Every positive value lands in a bin of one geometric grid anchored
+    at ``lo`` (``bins_per_decade`` bins per factor of 10, extending
+    below ``lo`` and above ``hi`` as far as the values go; counts are
+    kept sparse), so a percentile estimate is off by at most one bin
+    width — a bounded *relative* error of ``10**(1/bins_per_decade) - 1``
+    (~10% at the default 24/decade, halved in expectation by the
+    geometric-midpoint readout) for every finite positive value,
+    however many samples stream through. O(1) record, memory bounded by
+    the decades the values span, mergeable across histograms with
+    identical geometry (shard-local telemetry can be reduced without
+    keeping raw samples). ``hi`` is the nominal top of the range: part
+    of the geometry, not a bound.
 
-    Values below ``lo`` (including 0 — e.g. same-step inter-token gaps
-    from accepted speculative lanes) fall into a dedicated underflow
-    bin; values at or above ``hi`` into an overflow bin. Exact ``min``,
-    ``max``, ``sum`` and ``count`` ride along, and percentile readouts
-    are clamped to the observed [min, max].
+    Zero and negative values (e.g. same-step inter-token gaps from
+    accepted speculative lanes) fall into a dedicated zero bin. Exact
+    ``min``, ``max``, ``sum`` and ``count`` ride along; percentile
+    readouts are clamped to the observed [min, max], and the first and
+    last ranks read the exact min and max.
     """
 
     def __init__(self, lo: float = 1e-6, hi: float = 1e5,
@@ -107,33 +149,28 @@ class LogHistogram:
             raise ValueError(f"need 0 < lo < hi, got lo={lo} hi={hi}")
         self.lo, self.hi = float(lo), float(hi)
         self.bins_per_decade = int(bins_per_decade)
-        decades = math.log10(self.hi / self.lo)
-        # +2: underflow bin 0, overflow bin nbins-1.
-        self._nbins = int(math.ceil(decades * bins_per_decade)) + 2
-        self._counts = [0] * self._nbins
+        self._zero = 0                      # values <= 0
+        self._counts: Dict[int, int] = {}   # grid bin -> count
         self.count = 0
         self.sum = 0.0
         self.min: Optional[float] = None
         self.max: Optional[float] = None
 
     def _bin_of(self, v: float) -> int:
-        if v < self.lo:
-            return 0
-        if v >= self.hi:
-            return self._nbins - 1
-        return 1 + int(math.log10(v / self.lo) * self.bins_per_decade)
-
-    def _edges(self, b: int) -> Tuple[float, float]:
-        """[lower, upper) value edges of interior bin ``b``."""
-        lo = self.lo * 10.0 ** ((b - 1) / self.bins_per_decade)
-        hi = self.lo * 10.0 ** (b / self.bins_per_decade)
-        return lo, hi
+        """Grid bin of a positive value: ``[lo*g**b, lo*g**(b+1))`` with
+        ``g = 10**(1/bins_per_decade)``; negative below ``lo``. Taken in
+        log space, so no finite value overflows."""
+        return math.floor((math.log10(v) - math.log10(self.lo))
+                          * self.bins_per_decade)
 
     def record(self, v: float) -> None:
-        """Stream one value in (O(1), no allocation)."""
+        """Stream one value in (O(1))."""
         v = float(v)
-        b = self._bin_of(v)
-        self._counts[min(b, self._nbins - 1)] += 1
+        if v > 0.0:
+            b = self._bin_of(v)
+            self._counts[b] = self._counts.get(b, 0) + 1
+        else:
+            self._zero += 1
         self.count += 1
         self.sum += v
         self.min = v if self.min is None else min(self.min, v)
@@ -145,8 +182,9 @@ class LogHistogram:
                 (self.lo, self.hi, self.bins_per_decade):
             raise ValueError("cannot merge histograms with different "
                              "bin geometry")
-        for i, c in enumerate(other._counts):
-            self._counts[i] += c
+        self._zero += other._zero
+        for b, c in other._counts.items():
+            self._counts[b] = self._counts.get(b, 0) + c
         self.count += other.count
         self.sum += other.sum
         for attr in ("min", "max"):
@@ -168,22 +206,26 @@ class LogHistogram:
         Walks the cumulative counts to the bin holding rank
         ``ceil(q/100 * count)`` and reads its geometric midpoint,
         clamped to the observed [min, max] — so the estimate is within
-        one bin width (relative) of the exact nearest-rank quantile.
+        one bin width (relative) of the exact nearest-rank quantile. The
+        first and last ranks are the exact min and max.
         """
         if not self.count:
             return 0.0
         rank = max(1, int(math.ceil(q / 100.0 * self.count)))
-        acc = 0
-        for b, c in enumerate(self._counts):
-            acc += c
+        if rank >= self.count:
+            return self.max
+        if rank == 1:
+            return self.min
+        acc = self._zero
+        if acc >= rank:
+            return self.min                     # zero bin: all <= 0
+        for b in sorted(self._counts):
+            acc += self._counts[b]
             if acc >= rank:
-                if b == 0:                       # underflow: all < lo
-                    v = self.min if self.min is not None else 0.0
-                elif b == self._nbins - 1:       # overflow: all >= hi
-                    v = self.max if self.max is not None else self.hi
-                else:
-                    lo, hi = self._edges(b)
-                    v = math.sqrt(lo * hi)
+                # Geometric midpoint of the bin, in log space and capped
+                # at the max so that it cannot overflow.
+                mid = math.log10(self.lo) + (b + 0.5) / self.bins_per_decade
+                v = 10.0 ** min(mid, math.log10(self.max))
                 return min(max(v, self.min), self.max)
         return self.max                           # pragma: no cover
 
@@ -197,9 +239,9 @@ class LogHistogram:
         return {"lo": self.lo, "hi": self.hi,
                 "bins_per_decade": self.bins_per_decade,
                 "count": self.count, "sum": self.sum,
-                "min": self.min, "max": self.max,
-                "bins": {str(i): c for i, c in enumerate(self._counts)
-                         if c}}
+                "min": self.min, "max": self.max, "zero": self._zero,
+                "bins": {str(b): self._counts[b]
+                         for b in sorted(self._counts)}}
 
     @classmethod
     def from_dict(cls, d: Dict) -> "LogHistogram":
@@ -208,20 +250,21 @@ class LogHistogram:
         h.count, h.sum = int(d["count"]), float(d["sum"])
         h.min = d["min"] if d["min"] is None else float(d["min"])
         h.max = d["max"] if d["max"] is None else float(d["max"])
-        for i, c in d["bins"].items():
-            h._counts[int(i)] = int(c)
+        h._zero = int(d["zero"])
+        h._counts = {int(b): int(c) for b, c in d["bins"].items()}
         return h
 
 
 @dataclasses.dataclass
 class StepEvent:
-    """One engine step, structured: timing, phase mix, counter deltas
-    and the ledger-byte delta the step (plus any between-step admission
-    / reservation charges since the previous event) moved."""
+    """One engine step, structured: timing, host phases, phase mix,
+    counter deltas and the ledger-byte delta the step (plus any
+    between-step admission / reservation charges since the previous
+    event) moved."""
 
     step: int                       # 0-based step index
-    t_start: float                  # stream-relative seconds
-    t_end: float
+    t_start: float                  # serve-relative seconds: dispatch
+    t_end: float                    # ... to the host's sync on the tokens
     occupancy: int                  # active slots during the step
     compiles: int                   # jit compilations this step (0 or 1)
     counters: Dict[str, float]      # per-step DELTAS of GenStats/sched
@@ -231,25 +274,14 @@ class StepEvent:
     slots: List[Tuple[int, int, str, int, int]]
     ledger_delta: Dict[CellKey, float]
     draft_delta: Optional[Dict[CellKey, float]] = None
-    load_s: float = 0.0             # modeled DMA time of this delta
+    # Host phases of the serve-loop iteration that ran this step:
+    # {name: [t0, t1]} in serve-relative seconds (see ``SERVE_PHASES``).
+    spans: Dict[str, List[float]] = dataclasses.field(default_factory=dict)
 
     @property
     def wall_s(self) -> float:
-        """Measured step wall time (host-sync inclusive) — the EXEC side
-        of the per-step LOAD-vs-EXEC attribution."""
+        """Measured step wall time, dispatch to the host sync."""
         return self.t_end - self.t_start
-
-    @property
-    def load_share(self) -> float:
-        """Modeled-LOAD fraction of the step: load / (load + exec)."""
-        tot = self.load_s + self.wall_s
-        return self.load_s / tot if tot > 0 else 0.0
-
-    @property
-    def bound(self) -> str:
-        """"transfer" when the modeled DMA time dominates the measured
-        step time, else "compute" — the per-step §V.A attribution."""
-        return "transfer" if self.load_share >= 0.5 else "compute"
 
     def to_json_dict(self) -> Dict:
         """The JSONL ``step`` event for this step."""
@@ -261,110 +293,11 @@ class StepEvent:
              "slots": [list(s) for s in self.slots],
              "ledger_delta": {_cell_str(k): v
                               for k, v in self.ledger_delta.items()},
-             "load_s": self.load_s, "bound": self.bound}
+             "spans": {n: list(t) for n, t in self.spans.items()}}
         if self.draft_delta is not None:
             d["draft_delta"] = {_cell_str(k): v
                                 for k, v in self.draft_delta.items()}
         return d
-
-
-@dataclasses.dataclass
-class BottleneckReport:
-    """Transfer-bound vs compute-bound attribution over a timeline.
-
-    Per step: the modeled DMA time of the step's ledger delta
-    (``TransferModel`` — the same LOAD model behind
-    ``TransferLedger.load_seconds``) against the measured step wall
-    time. Aggregates reproduce the bench's LOAD-vs-EXEC report from the
-    live series: ``phase_load_s`` is computed from the summed deltas at
-    phase granularity with one coalesced transaction per phase, so it
-    equals ``ledger.load_seconds()`` on the same cells, and
-    ``phase_exec_s`` follows GenStats' pro-rata phase split."""
-
-    steps: int
-    transfer_bound: int             # steps with load_share >= 0.5
-    compute_bound: int
-    load_s: float                   # sum of per-step modeled DMA time
-    exec_s: float                   # sum of measured step wall time
-    phase_load_s: Dict[str, float]
-    phase_exec_s: Dict[str, float]
-    dp: int = 1
-    tp: int = 1
-    per_device_load_s: float = 0.0  # one device's share of the DMA time
-
-    @classmethod
-    def from_timeline(cls, timeline: "StepTimeline",
-                      ledger: TransferLedger) -> "BottleneckReport":
-        """Attribute every step of ``timeline`` and aggregate."""
-        tm = timeline.transfer_model
-        tb = load = ex = 0.0
-        tb = 0
-        phase_h2d = {p: 0.0 for p in PHASES}
-        phase_d2h = {p: 0.0 for p in PHASES}
-        phase_exec = {p: 0.0 for p in PHASES}
-        dev_h2d = dev_d2h = 0.0
-        for ev in timeline.events:
-            load += ev.load_s
-            ex += ev.wall_s
-            if ev.bound == "transfer":
-                tb += 1
-            pre = ev.counters.get("prefill_tokens", 0)
-            dec = ev.counters.get("decode_tokens", 0)
-            frac = pre / max(pre + dec, 1)
-            phase_exec["prefill"] += ev.wall_s * frac
-            phase_exec["decode"] += ev.wall_s * (1.0 - frac)
-            for (p, c, d), b in ev.ledger_delta.items():
-                if d == H2D:
-                    phase_h2d[p] += b
-                    dev_h2d += b * ledger.device_share(c)
-                elif d == D2H:
-                    phase_d2h[p] += b
-                    dev_d2h += b * ledger.device_share(c)
-        phase_load = {p: tm.load_time([phase_h2d[p]], True)
-                      + tm.drain_time(phase_d2h[p], True)
-                      if (phase_h2d[p] or phase_d2h[p]) else 0.0
-                      for p in PHASES}
-        return cls(steps=len(timeline.events), transfer_bound=tb,
-                   compute_bound=len(timeline.events) - tb,
-                   load_s=load, exec_s=ex, phase_load_s=phase_load,
-                   phase_exec_s=phase_exec, dp=ledger.dp, tp=ledger.tp,
-                   per_device_load_s=(
-                       tm.load_time([dev_h2d], True)
-                       + tm.drain_time(dev_d2h, True)
-                       if (dev_h2d or dev_d2h) else 0.0))
-
-    @property
-    def load_share(self) -> float:
-        """Aggregate modeled-LOAD fraction: load / (load + exec)."""
-        tot = self.load_s + self.exec_s
-        return self.load_s / tot if tot > 0 else 0.0
-
-    def to_dict(self) -> Dict:
-        """JSON-ready summary (what the JSONL ``summary`` line embeds)."""
-        return {"steps": self.steps,
-                "transfer_bound": self.transfer_bound,
-                "compute_bound": self.compute_bound,
-                "load_s": self.load_s, "exec_s": self.exec_s,
-                "load_share": self.load_share,
-                "phase_load_s": self.phase_load_s,
-                "phase_exec_s": self.phase_exec_s,
-                "dp": self.dp, "tp": self.tp,
-                "per_device_load_s": self.per_device_load_s}
-
-    def lines(self) -> List[str]:
-        """Human-readable attribution lines for the serve report."""
-        out = [f"bottleneck: {self.transfer_bound}/{self.steps} steps "
-               f"transfer-bound | modeled LOAD {self.load_s*1e3:.2f} ms "
-               f"vs measured EXEC {self.exec_s*1e3:.2f} ms "
-               f"(LOAD share {self.load_share*100:.1f}%)"]
-        if self.dp * self.tp > 1:
-            out.append(
-                f"bottleneck per-device (dp={self.dp} tp={self.tp}): "
-                f"modeled LOAD {self.per_device_load_s*1e3:.2f} ms "
-                f"({self.per_device_load_s / self.load_s:.3f}x "
-                f"aggregate)" if self.load_s else
-                "bottleneck per-device: no transfer recorded")
-        return out
 
 
 class StepTimeline:
@@ -382,6 +315,11 @@ class StepTimeline:
     The engine drives it with ``record_step`` after every unified step;
     the scheduler reports admissions/preemptions via ``on_admit`` /
     ``on_preempt``; token emission lands in ``on_token`` / ``on_done``.
+    Host phases arrive through ``span``: ``open_iteration`` starts each
+    serve-loop iteration, ``add_span`` records a phase on it, and the
+    step event that ``record_step`` makes holds the iteration's phases —
+    those that close after it (bookkeeping) too. An iteration that runs
+    no step leaves its phases in the profiler trace only.
     Everything is plain host-side Python on small dicts — no device
     interaction, no traced values, no effect on jit caches.
     """
@@ -392,12 +330,13 @@ class StepTimeline:
 
     def __init__(self, ledger: TransferLedger, *,
                  draft_ledger: Optional[TransferLedger] = None,
-                 transfer_model: Optional[TransferModel] = None,
                  meta: Optional[Dict] = None):
         self.ledger = ledger
         self.draft_ledger = draft_ledger
-        self.transfer_model = transfer_model or TransferModel()
         self.meta = dict(meta or {})
+        # perf_counter value of serve-relative time 0 (set by the engine).
+        self.origin = 0.0
+        self._spans: Dict[str, List[float]] = {}
         self.events: List[StepEvent] = []
         self.admissions: List[Tuple[int, float, float]] = []
         self.preemptions: List[Tuple[int, float]] = []
@@ -430,6 +369,15 @@ class StepTimeline:
         self._draft_cum[k] = self._draft_cum.get(k, 0.0) + nbytes
 
     # -- scheduler / engine hooks ----------------------------------------
+    def open_iteration(self) -> None:
+        """A serve-loop iteration begins: its phases go to a fresh map."""
+        self._spans = {}
+
+    def add_span(self, name: str, t0: float, t1: float) -> None:
+        """Record host phase ``name`` over perf_counter ``[t0, t1)`` on
+        the current iteration."""
+        self._spans[name] = [t0 - self.origin, t1 - self.origin]
+
     def on_admit(self, rid: int, t: float, queue_wait_s: float) -> None:
         """One admission: record the queue-age sample and the event."""
         self.admissions.append((rid, t, queue_wait_s))
@@ -475,11 +423,6 @@ class StepTimeline:
         self._prev_counters = dict(counters)
         led = self._step_delta
         self._step_delta = {}
-        h2d = sum(b for (_, _, d), b in led.items() if d == H2D)
-        d2h = sum(b for (_, _, d), b in led.items() if d == D2H)
-        tm = self.transfer_model
-        load = (tm.load_time([h2d], True) if h2d else 0.0) \
-            + (tm.drain_time(d2h, True) if d2h else 0.0)
         draft = None
         if self.draft_ledger is not None:
             draft = self._draft_step_delta
@@ -488,7 +431,7 @@ class StepTimeline:
                        t_end=t_end, occupancy=occupancy,
                        compiles=compiles, counters=delta, gauges=gauges,
                        slots=slots, ledger_delta=led, draft_delta=draft,
-                       load_s=load)
+                       spans=self._spans)
         self.events.append(ev)
         self.hists["step_wall_s"].record(ev.wall_s)
         self._now = max(self._now, t_end)
@@ -505,6 +448,7 @@ class StepTimeline:
         if self.draft_ledger is not None:
             self.draft_ledger.detach_tap()
         if self._step_delta or self._draft_step_delta:
+            self.open_iteration()
             self.record_step(t_start=t_end, t_end=t_end, occupancy=0,
                              compiles=0, counters=self._prev_counters,
                              gauges={}, slots=[])
@@ -527,16 +471,23 @@ class StepTimeline:
         """{metric: {"p50": ..., ...}} over every tracked histogram."""
         return {n: h.percentiles(qs) for n, h in self.hists.items()}
 
-    def bottleneck_report(self) -> BottleneckReport:
-        """Per-step LOAD-vs-EXEC attribution over the recorded events."""
-        return BottleneckReport.from_timeline(self, self.ledger)
+    def phase_ms(self) -> Dict[str, float]:
+        """Mean milliseconds per step of each host phase, over the step
+        events that carry phases, in ``SERVE_PHASES`` order."""
+        evs = [ev for ev in self.events if ev.spans]
+        out: Dict[str, float] = {}
+        for name in SERVE_PHASES:
+            tot = sum(ev.spans[name][1] - ev.spans[name][0]
+                      for ev in evs if name in ev.spans)
+            if evs:
+                out[name] = tot / len(evs) * 1e3
+        return out
 
     # -- exporters -------------------------------------------------------
     def write_metrics_jsonl(self, path: str) -> None:
         """Write the JSONL metrics sink: meta header, admit/preempt and
         step events in time order, and a final summary line with the
-        histograms, percentiles, bottleneck attribution and the
-        accumulated ledger totals."""
+        histograms, percentiles and the accumulated ledger totals."""
         with open(path, "w") as f:
             meta = {"event": "meta", "version": 1, "ts_unit": "s",
                     **self.meta}
@@ -554,7 +505,6 @@ class StepTimeline:
                 "histograms": {n: h.to_dict()
                                for n, h in self.hists.items()},
                 "percentiles": self.percentile_summary(),
-                "bottleneck": self.bottleneck_report().to_dict(),
                 "ledger_total": {
                     _cell_str(k): v for k, v in self._cum.items()},
             }
@@ -562,9 +512,12 @@ class StepTimeline:
 
     def chrome_trace_events(self) -> List[Dict]:
         """The Chrome-trace ``traceEvents`` list: per-slot span tracks
-        (phase-named complete events), an engine step track, instant
-        events for admissions/preemptions, and ledger-byte counter
-        tracks (cumulative h2d/d2h MB per category), sorted by ts."""
+        (phase-named complete events), an engine step track holding each
+        step and its host phases (dispatch and sync nest inside the step,
+        which they tile; schedule, operands and bookkeeping sit beside
+        it), instant events for admissions/preemptions, and ledger-byte
+        counter tracks (cumulative h2d/d2h MB per category), sorted by
+        ts (a parent before the children it starts with)."""
         US = 1e6
         evs: List[Dict] = []
         pid = 1
@@ -586,9 +539,11 @@ class StepTimeline:
                         "cat": "step", "ts": ts, "dur": dur,
                         "pid": pid, "tid": 0,
                         "args": {"occupancy": ev.occupancy,
-                                 "bound": ev.bound,
-                                 "load_ms": ev.load_s * 1e3,
                                  "compiles": ev.compiles}})
+            for name, (a, b) in ev.spans.items():
+                evs.append({"ph": "X", "name": name, "cat": "phase",
+                            "ts": a * US, "dur": max((b - a) * US, 1e-3),
+                            "pid": pid, "tid": 0, "args": {"step": ev.step}})
             for slot, rid, phase, fed, emitted in ev.slots:
                 evs.append({"ph": "X", "name": phase, "cat": "slot",
                             "ts": ts, "dur": dur, "pid": pid,
@@ -622,7 +577,8 @@ class StepTimeline:
             evs.append({"ph": "i", "name": f"preempt rid={rid}",
                         "s": "p", "ts": t * US, "pid": pid, "tid": 0,
                         "args": {}})
-        evs.sort(key=lambda e: (e.get("ts", -1.0), e.get("ph") != "M"))
+        evs.sort(key=lambda e: (e.get("ts", -1.0), e.get("ph") != "M",
+                                -e.get("dur", 0.0)))
         return evs
 
     def write_chrome_trace(self, path: str) -> None:
@@ -643,8 +599,10 @@ def validate_metrics_jsonl(path: str) -> int:
     Checks: every line parses as a JSON object with a known ``event``
     type carrying that type's required keys; exactly one leading meta
     and one trailing summary line; step indices dense from 0 with
-    monotone non-decreasing ``t_start``. Returns the number of step
-    events; raises ``ValueError`` on any violation.
+    monotone non-decreasing ``t_start``; each step's ``spans`` a map of
+    phase name to ``[t0, t1]`` with ``t0 <= t1``, the phases disjoint.
+    Returns the number of step events; raises ``ValueError`` on any
+    violation.
     """
     steps = 0
     last_t = -math.inf
@@ -674,6 +632,7 @@ def validate_metrics_jsonl(path: str) -> int:
                     raise ValueError(f"{path}:{i + 1}: t_start moved "
                                      "backwards")
                 last_t = obj["t_start"]
+                _check_spans(obj["spans"], f"{path}:{i + 1}")
                 steps += 1
             lines.append(kind)
     if not lines or lines[0] != "meta":
@@ -684,6 +643,24 @@ def validate_metrics_jsonl(path: str) -> int:
         raise ValueError(f"{path}: exactly one meta and one summary "
                          "line required")
     return steps
+
+
+def _check_spans(spans, where: str) -> None:
+    """A step event's ``spans``: numeric ``[t0, t1]`` pairs, each in
+    order, none overlapping another."""
+    if not isinstance(spans, dict):
+        raise ValueError(f"{where}: spans must be an object")
+    ivs = []
+    for name, iv in spans.items():
+        if not (isinstance(iv, list) and len(iv) == 2 and all(
+                isinstance(t, (int, float)) for t in iv) and iv[0] <= iv[1]):
+            raise ValueError(f"{where}: span {name!r} is not [t0, t1] "
+                             "with t0 <= t1")
+        ivs.append(iv)
+    ivs.sort()
+    for a, b in zip(ivs, ivs[1:]):
+        if b[0] < a[1]:
+            raise ValueError(f"{where}: spans overlap")
 
 
 def validate_chrome_trace(path: str) -> int:
@@ -746,9 +723,9 @@ def serve_report_lines(engine, report,
     lines that used to overlap ``TransferReport.summary_lines`` —
     scheduler/occupancy, paged-arena, prefix-cache, speculative, timing,
     latency-percentile (telemetry histograms when available, finished-
-    sequence lists otherwise), mesh, ledger LOAD-vs-EXEC and bottleneck
-    attribution lines are all emitted here, so the CLI report and the
-    summary cells cannot drift apart. ``total_requests`` defaults to the
+    sequence lists otherwise), mesh, ledger and host-phase lines are all
+    emitted here, so the CLI report and the summary cells cannot drift
+    apart. ``total_requests`` defaults to the
     finished-sequence count (a serve run drains its stream)."""
     st = report.stats
     sched = report.sched
@@ -828,8 +805,10 @@ def serve_report_lines(engine, report,
     exec_s = {"prefill": st.prefill_s, "decode": st.decode_s}
     lines.extend(f"  {ln}"
                  for ln in report.ledger.summary_lines(exec_s))
-    if tl is not None:
-        lines.extend(tl.bottleneck_report().lines())
+    phases = tl.phase_ms() if tl is not None else {}
+    if phases:
+        lines.append("host phases (mean ms/step): " + " | ".join(
+            f"{n.split('.', 1)[1]} {v:.2f}" for n, v in phases.items()))
     return lines
 
 

@@ -1,9 +1,9 @@
-"""Per-step serving telemetry tests (ISSUE 10): bit-exact ledger-delta
-closure through the charge tap (including the preemption, speculative-
-rollback and prefix-hit paths), streaming log-histogram accuracy against
-exact nearest-rank quantiles, JSONL/Perfetto export schema validity, and
-the observability contract — telemetry on/off serves are token-identical
-with ONE step compile."""
+"""Per-step serving telemetry tests: bit-exact ledger-delta closure
+through the charge tap (including the preemption, speculative-rollback
+and prefix-hit paths), streaming log-histogram accuracy against exact
+nearest-rank quantiles, the serve loop's host phase spans, JSONL/Perfetto
+export schema validity, and the observability contract — telemetry
+on/off serves are token-identical with ONE step compile."""
 import json
 import math
 
@@ -15,8 +15,8 @@ from repro.configs.registry import ASSIGNED
 from repro.models.api import build_model
 from repro.runtime.engine import ServingEngine
 from repro.runtime.request import Request
-from repro.runtime.telemetry import (BottleneckReport, LogHistogram,
-                                     StepTimeline, serve_report_lines,
+from repro.runtime.telemetry import (SERVE_PHASES, LogHistogram,
+                                     StepTimeline, serve_report_lines, span,
                                      validate_chrome_trace,
                                      validate_metrics_jsonl)
 from repro.runtime.transfers import TransferLedger
@@ -102,6 +102,28 @@ def test_histogram_merge_matches_union():
     assert da == du
     with pytest.raises(ValueError):
         a.merge(LogHistogram(bins_per_decade=8))
+
+
+def test_histogram_values_beyond_the_nominal_range():
+    """Values at or above ``hi`` and below ``lo`` keep their own bins on
+    the one geometric grid, so every rank reads within one bin width
+    (once, everything at or above ``hi`` read back as the observed max:
+    p10 of [1e5, 110070] read 110070)."""
+    cases = [[100000.0, 110070.0], [1e5, 1.5e5, 3e5, 7e8],
+             [1e-9, 3e-8, 5e-7, 2e-6], [2e-7] * 3 + [1e5, 4e6]]
+    for vals in cases:
+        h = LogHistogram()
+        for v in vals:
+            h.record(v)
+        s = sorted(vals)
+        for q in (10, 25, 50, 75, 90, 99):
+            exact = exact_nearest_rank(s, q)
+            assert abs(h.percentile(q) - exact) <= exact * hist_bound(h), \
+                (vals, q, h.percentile(q))
+    h = LogHistogram()
+    for v in (1e-9, 1e300):                 # no finite value overflows
+        h.record(v)
+    assert h.percentile(10) == 1e-9 and h.percentile(99) == 1e300
 
 
 def test_histogram_dict_roundtrip():
@@ -194,6 +216,9 @@ def test_token_identity_compiles_and_closure(served_model):
         assert a.generated == b.generated
     assert r_on.step_compiles == 1
     tl = r_on.timeline
+    # spans on: every step carries its host phases, and nothing else moved
+    assert tl.events and all(set(ev.spans) == set(SERVE_PHASES)
+                             for ev in tl.events)
     assert tl.ledger_delta_totals() == r_on.ledger.breakdown()
     assert sum(ev.counters.get("decode_tokens", 0) for ev in tl.events) \
         == r_on.stats.decode_tokens
@@ -252,6 +277,9 @@ def test_closure_under_speculative_rollback(served_model):
 
 
 def test_exports_validate_and_bottleneck(served_model, tmp_path):
+    """Both exports carry the host phase spans and pass their validators
+    (the name predates the spans: the exports once carried a modeled
+    transfer-bound verdict per step)."""
     cfg, model, params = served_model
     eng = ServingEngine(model, params, num_slots=2, max_seq=16,
                         chunk_size=4, telemetry=True)
@@ -267,6 +295,7 @@ def test_exports_validate_and_bottleneck(served_model, tmp_path):
     lines = [json.loads(ln) for ln in mpath.read_text().splitlines()]
     assert lines[0]["event"] == "meta"
     assert lines[-1]["event"] == "summary"
+    assert "bottleneck" not in lines[-1]
     steps = [ln for ln in lines if ln["event"] == "step"]
     # JSONL step deltas re-sum to the ledger totals (serialized floats)
     tot = {}
@@ -277,20 +306,80 @@ def test_exports_validate_and_bottleneck(served_model, tmp_path):
     assert set(tot) == set(k for k, v in flat.items() if v)
     for k, v in tot.items():
         assert v == pytest.approx(flat[k], rel=1e-9)
+    # every step line carries its phases, as recorded
+    assert [s["spans"] for s in steps] == \
+        [{n: list(t) for n, t in ev.spans.items()} for ev in tl.events]
+    assert all(set(s["spans"]) == set(SERVE_PHASES) for s in steps)
 
     trace = json.loads(tpath.read_text())
     assert isinstance(trace["traceEvents"], list)
     ts = [e["ts"] for e in trace["traceEvents"] if e["ph"] != "M"]
     assert ts == sorted(ts)
     assert any(e["ph"] == "C" for e in trace["traceEvents"])
+    # each phase is a span on the engine step track; dispatch and sync
+    # nest inside their step's span
+    phases = [e for e in trace["traceEvents"] if e.get("cat") == "phase"]
+    assert len(phases) == len(SERVE_PHASES) * len(tl.events)
+    assert all(e["tid"] == 0 and e["dur"] > 0 for e in phases)
+    step_box = {int(e["name"].split()[1]): e for e in trace["traceEvents"]
+                if e.get("cat") == "step"}
+    for e in phases:
+        if e["name"] in ("serve.dispatch", "serve.sync"):
+            box = step_box[e["args"]["step"]]
+            assert box["ts"] <= e["ts"] and \
+                e["ts"] + e["dur"] <= box["ts"] + box["dur"] + 1e-6
 
-    br = tl.bottleneck_report()
-    assert isinstance(br, BottleneckReport)
-    assert br.steps == len(tl.events)
-    assert br.transfer_bound + br.compute_bound == br.steps
-    led_load = rep.ledger.load_seconds()
-    for p, v in br.phase_load_s.items():
-        assert v == pytest.approx(led_load.get(p, 0.0), rel=1e-6)
+
+def test_validator_rejects_overlapping_spans(served_model, tmp_path):
+    cfg, model, params = served_model
+    eng = ServingEngine(model, params, num_slots=2, max_seq=16,
+                        chunk_size=4, telemetry=True)
+    rep = eng.serve(make_requests(cfg, 2, 2, seed=3, hi=9), seed=0,
+                    realtime=False)
+    ev = rep.timeline.events[0]
+    a, b = ev.spans["serve.sync"]
+    ev.spans["serve.bookkeep"][0] = a        # starts inside sync
+    path = tmp_path / "m.jsonl"
+    rep.timeline.write_metrics_jsonl(str(path))
+    with pytest.raises(ValueError, match="overlap"):
+        validate_metrics_jsonl(str(path))
+
+
+def test_step_events_carry_disjoint_ordered_phases(served_model):
+    """Every step of a serve carries the five step phases, disjoint and
+    in order inside their iteration, with the step's t_start/t_end equal
+    to the dispatch's start and the sync's end; a span outside the engine
+    records on the timeline it is given."""
+    cfg, model, params = served_model
+    eng = ServingEngine(model, params, num_slots=3, max_seq=24,
+                        chunk_size=4, block_size=4, num_blocks=20,
+                        paged_attn="fused", telemetry=True)
+    rep = eng.serve(make_requests(cfg, 5, 4, seed=8), seed=0,
+                    realtime=False)
+    tl = rep.timeline
+    assert len(tl.events) == rep.stats.steps > 0
+    prev_end = 0.0
+    for ev in tl.events:
+        assert list(ev.spans) == list(SERVE_PHASES)
+        ivs = [ev.spans[n] for n in SERVE_PHASES]
+        for (a0, a1), (b0, b1) in zip(ivs, ivs[1:]):
+            assert a0 <= a1 <= b0 <= b1
+        assert ivs[0][0] >= prev_end              # after the last step
+        prev_end = ivs[-1][1]
+        assert ev.t_start == ev.spans["serve.dispatch"][0]
+        assert ev.t_end == ev.spans["serve.sync"][1]
+    assert set(tl.phase_ms()) == set(SERVE_PHASES)
+
+    led = TransferLedger(cfg, "none")
+    own = StepTimeline(led)
+    own.origin = 10.0
+    with span("custom.phase", own) as rec:
+        pass
+    assert rec.t0 <= rec.t1
+    assert own._spans["custom.phase"] == [rec.t0 - 10.0, rec.t1 - 10.0]
+    with span("custom.phase") as rec:        # no timeline: trace only
+        pass
+    assert rec.t1 >= rec.t0
 
 
 def test_serve_report_lines_smoke(served_model):
@@ -300,8 +389,8 @@ def test_serve_report_lines_smoke(served_model):
     rep = eng.serve(make_requests(cfg, 3, 3, seed=4, hi=9), seed=0,
                     realtime=False)
     text = "\n".join(serve_report_lines(eng, rep, total_requests=3))
-    for needle in ("step compiles", "mean queue wait", "bottleneck",
-                   "p50", "transfer-bound"):
+    for needle in ("step compiles", "mean queue wait", "host phases",
+                   "p50", "bookkeep"):
         assert needle in text, f"report lines missing {needle!r}"
 
 
