@@ -70,8 +70,8 @@ def paged_attention_bench() -> None:
     perm = rng.permutation(nb)
     tables = np.stack([perm[i * mb:(i + 1) * mb] for i in range(B)]) \
         .astype(np.int32)
-    k_pages = jnp.asarray(rng.randn(nb + 1, Hkv, bs, D), jnp.float32)
-    v_pages = jnp.asarray(rng.randn(nb + 1, Hkv, bs, D), jnp.float32)
+    k_pages = jnp.asarray(rng.randn(1, nb + 1, Hkv, bs, D), jnp.float32)
+    v_pages = jnp.asarray(rng.randn(1, nb + 1, Hkv, bs, D), jnp.float32)
     q = jnp.asarray(rng.randn(B, C, H, D), jnp.float32)
     pos0 = jnp.asarray([17, 3, 40, 9], jnp.int32)   # live depths << mb*bs
     sm = D ** -0.5

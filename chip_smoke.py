@@ -106,7 +106,7 @@ def kernel_differential(cfg, seed: int) -> float:
                          .reshape(SLOTS, mb).astype(np.int32))
     pos0 = jnp.asarray(rng.randint(0, MAX_SEQ - CHUNK + 1, SLOTS), jnp.int32)
     keys = jax.random.split(jax.random.PRNGKey(seed + 2), 3)
-    pages = (SLOTS * mb + 1, hkv, BLOCK, d)
+    pages = (1, SLOTS * mb + 1, hkv, BLOCK, d)      # a one-layer stack
     kp = jax.random.normal(keys[0], pages, jnp.bfloat16)
     vp = jax.random.normal(keys[1], pages, jnp.bfloat16)
     q = jax.random.normal(keys[2], (SLOTS, CHUNK, h, d), jnp.bfloat16)

@@ -14,12 +14,16 @@ online-softmax skeleton of ``kernels/flash_attention.py``).
 Layout contract (see ``PagedKVArena.page_layout``):
 
   q        (B, C, H, D)        — C >= 1 chunked-decode queries per slot
-  k_pages  (NP, Hkv, bs, D)    — head-major physical pages incl. the
-  v_pages  (NP, Hkv, bs, Dv)     trailing null page; H % Hkv == 0 (GQA
-                                 groups). One kernel block is one
-                                 (page, kv-head) tile (bs, D): its last
-                                 two dims are the array's own, which is
-                                 what Mosaic's (8, 128) tiling rule needs
+  k_pages  (L, NP, Hkv, bs, D) — the arena's stacked head-major physical
+  v_pages  (L, NP, Hkv, bs, Dv)  pages of every layer incl. the trailing
+                                 null page; H % Hkv == 0 (GQA groups).
+                                 One kernel block is one (layer, page,
+                                 kv-head) tile (bs, D): its last two dims
+                                 are the array's own, which is what
+                                 Mosaic's (8, 128) tiling rule needs
+  layer    scalar int32        — the layer whose pages are read; the
+                                 stacked arena is read in place, never
+                                 sliced per layer
   tables   (B, MB) int32       — logical block -> physical page; entries
                                  past a slot's allocation hold the null
                                  page id (finite garbage, always masked)
@@ -35,8 +39,8 @@ attended in place, never expanded *and* never gathered.
 
 Quantized pages (``k_scales`` passed): K/V pages hold int8 codes and a
 parallel *scale page* per pages operand carries one float16 scale per
-(kv-head, in-page position) — ``(NP, Hkv, bs)`` next to ``(NP, Hkv,
-bs, D)`` — quantized over the feature axis at insert time (see
+(kv-head, in-page position) — ``(L, NP, Hkv, bs)`` next to ``(L, NP,
+Hkv, bs, D)`` — quantized over the feature axis at insert time (see
 ``PagedKVArena.page_layout``). A scale block is the whole ``(Hkv, bs)``
 page (full dims, so it tiles); the kernel picks its kv-head's row and
 dequantizes in VMEM inside the block walk (``k = int8 * scale`` in
@@ -70,9 +74,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import MASK_VALUE
 
 
-def _kernel(tables_ref, pos_ref, len_ref, *refs, sm_scale, block_size,
-            group, has_rope, shared_kv, quantized):
+def _kernel(tables_ref, pos_ref, len_ref, layer_ref, *refs, sm_scale,
+            block_size, group, has_rope, shared_kv, quantized):
     """One (slot, kv-head, kv-block) step of the online softmax."""
+    del layer_ref                       # read by the index maps only
     refs = list(refs)
     q1_ref = refs.pop(0)
     q2_ref = refs.pop(0) if has_rope else None
@@ -92,9 +97,9 @@ def _kernel(tables_ref, pos_ref, len_ref, *refs, sm_scale, block_size,
         code * per-(kv-head, position) scale — a zeroed page (code 0,
         scale 0) dequantizes to exactly 0, preserving the rollback
         bit-identity contract on the quantized layout."""
-        x = ref[0, 0].astype(jnp.float32)
+        x = ref[0, 0, 0].astype(jnp.float32)
         if s_ref is not None:
-            x = x * _scale_column(_f16_bits_to_f32(s_ref[0]), hkv_idx)
+            x = x * _scale_column(_f16_bits_to_f32(s_ref[0, 0]), hkv_idx)
         return x
     b = pl.program_id(0)
     hkv_idx = pl.program_id(1)
@@ -195,33 +200,36 @@ def _fold_heads(x, b, c, hkv, group):
     jax.jit,
     static_argnames=("sm_scale", "out_dtype", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
-                           sm_scale: float, q2=None, k2_pages=None,
+                           sm_scale: float, layer=0, q2=None, k2_pages=None,
                            k_scales=None, v_scales=None, k2_scales=None,
                            lengths=None, out_dtype=None,
                            interpret: bool = False):
     """Fused paged decode attention over a chunk of C queries per slot.
 
-    q: (B, C, H, D); k_pages/v_pages: (NP, Hkv, bs, D/Dv) head-major
-    physical pages (NP includes the arena's trailing null page);
-    block_tables: (B, MB) int32; positions: (B,) int32 chunk base
-    positions. ``v_pages=None`` shares the K pages as V (MLA's
-    compressed latents are both — one DMA per live block instead of
-    two). Optional q2 (B, C, H, D2) /
-    k2_pages (NP, Hkv, bs, D2) contribute a second score term before
+    q: (B, C, H, D); k_pages/v_pages: (L, NP, Hkv, bs, D/Dv) the
+    arena's stacked head-major physical pages (NP includes the trailing
+    null page); ``layer``: scalar int32, the layer attended — it rides
+    as a scalar-prefetch operand into the page index maps, so the
+    stacked leaf is read in place (a caller holding one layer's pages
+    passes ``pages[None]`` and layer 0); block_tables: (B, MB) int32;
+    positions: (B,) int32 chunk base positions. ``v_pages=None``
+    shares the K pages as V (MLA's compressed latents are both — one
+    DMA per live block instead of two). Optional q2 (B, C, H, D2) /
+    k2_pages (L, NP, Hkv, bs, D2) contribute a second score term before
     the softmax (MLA's decoupled-RoPE side). ``lengths`` (B,) int32:
     valid queries per row (chunked prefill) — each row's block walk
     stops at its last *valid* query's causal depth, so a steady-state
     decode row (lengths == 1) never over-fetches for its garbage tail.
 
     Quantized arenas pass int8 pages plus ``k_scales``/``v_scales``/
-    ``k2_scales`` — (NP, Hkv, bs) per-(kv-head, position) scale pages
+    ``k2_scales`` — (L, NP, Hkv, bs) per-(kv-head, position) scale pages
     riding the same block-table index map — and the kernel dequantizes
     inside the block walk (all scale operands must accompany their
     pages; ``v_scales`` is omitted exactly when ``v_pages`` is).
     Returns (B, C, H, Dv) in ``out_dtype`` (default q.dtype).
     """
     b, c, h, d = q.shape
-    num_pages, hkv, bs, _ = k_pages.shape
+    _, _, hkv, bs, _ = k_pages.shape
     shared_kv = v_pages is None
     quantized = k_scales is not None
     if quantized:
@@ -239,24 +247,28 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
     if lengths is None:
         lengths = jnp.full((b,), c, jnp.int32)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def page_index(bb, hh, jj, tables, pos, lens):
+    def live_page(bb, jj, tables, pos, lens):
         # Clamp dead blocks to the last live one: Pallas skips the
         # re-fetch of an unchanged block index, so trailing table entries
         # cost no DMA (the compute is skipped by pl.when).
         last = (pos[bb] + jnp.maximum(lens[bb], 1) - 1) // bs
-        return (tables[bb, jnp.minimum(jj, last)], hh, 0, 0)
+        return tables[bb, jnp.minimum(jj, last)]
 
-    def q_index(bb, hh, jj, tables, pos, lens):
+    def page_index(bb, hh, jj, tables, pos, lens, lyr):
+        return (lyr[0], live_page(bb, jj, tables, pos, lens), hh, 0, 0)
+
+    def q_index(bb, hh, jj, tables, pos, lens, lyr):
         return (bb, hh, 0, 0)
 
-    def scale_index(bb, hh, jj, tables, pos, lens):
-        # Scale pages (NP, Hkv, bs) ride the same clamped table walk as
-        # their int8 pages — one extra (Hkv, bs) fetch per live block.
-        last = (pos[bb] + jnp.maximum(lens[bb], 1) - 1) // bs
-        return (tables[bb, jnp.minimum(jj, last)], 0, 0)
+    def scale_index(bb, hh, jj, tables, pos, lens, lyr):
+        # Scale pages (L, NP, Hkv, bs) ride the same clamped table walk
+        # as their int8 pages — one extra (Hkv, bs) fetch per live block.
+        return (lyr[0], live_page(bb, jj, tables, pos, lens), 0, 0)
 
-    scale_spec = pl.BlockSpec((1, hkv, bs), scale_index)
+    page_spec = lambda dim: pl.BlockSpec((1, 1, 1, bs, dim), page_index)
+    scale_spec = pl.BlockSpec((1, 1, hkv, bs), scale_index)
     # float16 scale pages travel as their int16 bits (see _f16_bits_to_f32).
     bits = lambda s: jax.lax.bitcast_convert_type(
         jnp.asarray(s, jnp.float16), jnp.int16)
@@ -266,27 +278,26 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
         d2 = q2.shape[-1]
         in_specs.append(pl.BlockSpec((1, 1, cg, d2), q_index))
         args.append(_fold_heads(q2, b, c, hkv, group))
-    in_specs.append(pl.BlockSpec((1, 1, bs, d), page_index))
+    in_specs.append(page_spec(d))
     args.append(k_pages)
     if quantized:
         in_specs.append(scale_spec)
         args.append(bits(k_scales))
     if has_rope:
-        in_specs.append(pl.BlockSpec((1, 1, bs, k2_pages.shape[-1]),
-                                     page_index))
+        in_specs.append(page_spec(k2_pages.shape[-1]))
         args.append(k2_pages)
         if quantized:
             in_specs.append(scale_spec)
             args.append(bits(k2_scales))
     if not shared_kv:
-        in_specs.append(pl.BlockSpec((1, 1, bs, dv), page_index))
+        in_specs.append(page_spec(dv))
         args.append(v_pages)
         if quantized:
             in_specs.append(scale_spec)
             args.append(bits(v_scales))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, hkv, nkb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, cg, dv), q_index),
@@ -307,6 +318,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32), positions, lengths, *args)
+    )(jnp.asarray(block_tables, jnp.int32), positions, lengths, layer, *args)
     out = out.reshape(b, hkv, c, group, dv)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, c, h, dv)

@@ -18,12 +18,16 @@ KV caches:
   MLA:  {"ckv": (B, S, kv_lora_rank), "krope": (B, S, rope_dim)}  (compressed;
         decode uses the absorbed-matmul form so the cache is never expanded)
 
-Paged decode (``block_tables`` passed): cache leaves are head-major
-physical pages — GQA {"k": (NB, Hkv, bs, D), ...}, MLA {"ckv": (NB, 1,
-bs, rank), ...} (the latents carry a unit head axis, so every paged
-leaf has its position axis at 2) — and ``block_tables`` (B,
-max_blocks) int32 maps each slot's logical blocks to pages. The new token is scattered to its page, then attention reads K/V
-through the table one of two ways (``paged_impl``):
+Paged decode (``block_tables`` passed): cache leaves are the arena's
+stacked head-major physical pages of every layer — GQA {"k": (L, NB,
+Hkv, bs, D), ...}, MLA {"ckv": (L, NB, 1, bs, rank), ...} (the latents
+carry a unit head axis, so every paged leaf has its position axis at
+3) — and ``layer`` picks the layer this call reads and writes, so the
+leaf stays in place through the step: never sliced, never copied.
+``block_tables`` (B, max_blocks) int32 maps each slot's logical blocks
+to pages. The new tokens are scattered to their pages as
+``(layer, page, head, offset)`` rows, then attention reads K/V through
+the table one of two ways (``paged_impl``):
 
 * ``"fused"`` (default): the Pallas blocked online-softmax kernel in
   ``kernels/paged_attention.py`` resolves (slot, kv_block) -> page via
@@ -36,7 +40,7 @@ through the table one of two ways (``paged_impl``):
 
 Quantized paged cache (arena built with ``kv_quant="int8"``): each paged
 leaf is a dict ``{"q": int8 code pages, "s": float16 scale pages}``
-(scale pages (NB, Hkv, bs): one scale per (kv-head, position),
+(scale pages (L, NB, Hkv, bs): one scale per (kv-head, position),
 quantized over the feature axis at insert time — see ``quantize_kv``). The decode paths detect the dict
 structurally, quantize on insert, and either hand code+scale pages to
 the fused kernel (which dequantizes in the block walk) or dequantize the
@@ -45,10 +49,12 @@ quantized (the serving engine gates ``kv_quant`` on the paged arena).
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention import paged_decode_attention
@@ -333,91 +339,145 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 
 
 def _paged_insert_quant(leaf: Dict, new: jnp.ndarray, position,
-                        block_tables, lengths) -> Dict:
+                        block_tables, lengths, layer=0) -> Dict:
     """Quantize-on-insert into a quantized paged leaf ``{"q": int8
     pages, "s": scale pages}`` (see ``PagedKVArena.page_layout``). Codes
     and scales scatter through the same table walk, so the write-drop /
     null-block routing contracts apply to both identically."""
     q, s = quantize_kv(new)
     return {"q": paged_insert_token(leaf["q"], q, position, block_tables,
-                                    lengths),
+                                    lengths, layer),
             "s": paged_insert_token(leaf["s"], s, position, block_tables,
-                                    lengths)}
+                                    lengths, layer)}
 
 
-def _paged_view_dequant(leaf: Dict, block_tables) -> jnp.ndarray:
+def _paged_view_dequant(leaf: Dict, block_tables, layer=0) -> jnp.ndarray:
     """Dense-gather oracle over a quantized paged leaf: gather codes and
     scales through the table, dequantize to the f32 logical view."""
-    return dequantize_kv(paged_view(leaf["q"], block_tables),
-                         paged_view(leaf["s"], block_tables))
+    return dequantize_kv(paged_view(leaf["q"], block_tables, layer),
+                         paged_view(leaf["s"], block_tables, layer))
 
 
 # ----------------------------------------------------------------------
 # Paged cache plumbing (block-table gather/scatter inside the jitted step)
 # ----------------------------------------------------------------------
 def paged_insert_token(pages: jnp.ndarray, new: jnp.ndarray, position,
-                       block_tables: jnp.ndarray,
-                       lengths=None) -> jnp.ndarray:
-    """Scatter (B, C, H, ...) ``new`` into head-major (NB, H, bs, ...)
-    ``pages`` routed through ``block_tables`` (B, max_blocks). C == 1
-    with ``lengths`` None is the classic one-token write at
-    ``position``; the chunk form writes row b's first ``lengths[b]``
-    entries at ``position[b] + i``.
+                       block_tables: jnp.ndarray, lengths=None,
+                       layer=0) -> jnp.ndarray:
+    """Scatter (B, C, H, ...) ``new`` into layer ``layer`` of the stacked
+    head-major (L, NB, H, bs, ...) ``pages`` routed through
+    ``block_tables`` (B, max_blocks). C == 1 with ``lengths`` None is the
+    classic one-token write at ``position``; the chunk form writes row
+    b's first ``lengths[b]`` entries at ``position[b] + i``.
 
-    Blocks are uniquely owned by one slot, so active slots never collide;
-    single-token writes from inactive slots land in the arena's null block
-    (their table entries all point there), while chunk writes past a row's
-    valid length are routed out of range and *dropped* by the scatter."""
-    bs = pages.shape[2]
+    The write is tiled: the leaf is viewed (a bitcast) as (L, NB, H,
+    bs/T, T, ...) and each slot writes the whole T-position tiles its
+    valid entries fall in, per head, at ``(layer, page, head, tile)``:
+    each tile is its current contents with that slot's entries put in.
+    The index arrays are broadcast over (B, tiles, H), so no axis is
+    sliced and the window is whole trailing dims: the scatter needs no
+    layout but the arena's own, and the donated leaf is written in
+    place. ``_tile_rows`` picks T.
+
+    Blocks are uniquely owned by one slot, so active slots never collide
+    (the tiles a slot writes lie on its own pages; a tile written twice
+    by one slot carries the same values). Single-token writes from
+    inactive slots land in the arena's null block (their table entries
+    all point there); entries past a row's valid length keep the page's
+    current values, and a row with none writes nothing."""
+    num_pages, h, bs = pages.shape[1:4]
+    feat = pages.shape[4:]                  # (D,), or () on scale pages
     b, c = new.shape[:2]
-    pos0 = jnp.broadcast_to(jnp.asarray(position), (b,))
-    pos = pos0[:, None] + jnp.arange(c)                  # (B, C)
-    blk = pos // bs
-    phys = jnp.take_along_axis(block_tables, blk, axis=1)
-    if lengths is not None:
-        valid = jnp.arange(c)[None, :] < lengths[:, None]
-        phys = jnp.where(valid, phys, pages.shape[0])    # OOB -> dropped
-    # Indices on axes 0 and 2 around a slice: the (B, C) index dims lead
-    # the update, so ``new`` (B, C, H, ...) lines up as it is.
-    return pages.at[phys, :, pos % bs].set(new.astype(pages.dtype),
-                                           mode="drop")
+    n = jnp.full((b,), c, jnp.int32) if lengths is None else lengths
+    t = _tile_rows(pages)
+    nt = -(-c // t) + 1                     # tiles a chunk can touch
+    pos0 = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
+    last = (pos0 + jnp.maximum(n, 1) - 1) // t
+    tile = jnp.minimum(pos0[:, None] // t + jnp.arange(nt), last[:, None])
+    start = tile * t                                     # (B, nt)
+    phys = jnp.take_along_axis(block_tables, start // bs, axis=1)
+    phys = jnp.where(n[:, None] > 0, phys, num_pages)    # OOB -> dropped
+    # The chunk entry each tile position takes, where one does.
+    src = start[:, :, None] + jnp.arange(t) - pos0[:, None, None]
+    put = (src >= 0) & (src < n[:, None, None])          # (B, nt, T)
+    src = jnp.clip(src, 0, c - 1).reshape(b, nt * t)
+    src = src.reshape(src.shape + (1,) * (new.ndim - 2))
+    vals = jnp.take_along_axis(new.astype(pages.dtype), src, axis=1)
+    vals = jnp.moveaxis(vals.reshape((b, nt, t, h) + feat), 3, 2)
+    put = put[:, :, None].reshape((b, nt, 1, t) + (1,) * len(feat))
+    tiles = pages.reshape(pages.shape[:3] + (bs // t, t) + feat)
+    rows = (b, nt, h)
+    idx = (jnp.broadcast_to(jnp.asarray(layer, jnp.int32), rows),
+           jnp.broadcast_to(phys[:, :, None], rows),
+           jnp.broadcast_to(jnp.arange(h), rows),
+           jnp.broadcast_to((start % bs // t)[:, :, None], rows))
+    merged = jnp.where(put, vals, tiles.at[idx].get(mode="clip"))
+    return tiles.at[idx].set(merged, mode="drop").reshape(pages.shape)
 
 
-def paged_view(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-    """Gather each slot's logical KV view (B, max_blocks*bs, H, ...) from
-    head-major (NB, H, bs, ...) pages. Unassigned table entries gather the
-    null block; those positions sit past kv_len and are masked to NEG_INF
-    before the softmax, so their (finite) garbage never contributes."""
-    v = jnp.swapaxes(pages[block_tables], 2, 3)  # (B, max_blocks, bs, H, ...)
+def _tile_rows(pages: jnp.ndarray) -> int:
+    """Positions per written tile: the rows of one (8, 128) TPU memory
+    tile, packed 4 // itemsize to a 32-bit word (8 f32, 16 bf16, 32 int8
+    rows), so a tile is written whole, with no read-modify-write of
+    packed words, and with 8-16 times fewer writes than one per row.
+    Scale pages hold positions on the lane axis: a tile is a head's
+    whole row."""
+    bs = pages.shape[3]
+    if pages.ndim == 4:
+        return bs
+    return math.gcd(bs, 8 * 4 // pages.dtype.itemsize)
+
+
+def paged_view(pages: jnp.ndarray, block_tables: jnp.ndarray,
+               layer=0) -> jnp.ndarray:
+    """Gather each slot's logical KV view (B, max_blocks*bs, H, ...) of
+    layer ``layer`` from stacked head-major (L, NB, H, bs, ...) pages.
+    Unassigned table entries gather the null block; those positions sit
+    past kv_len and are masked to NEG_INF before the softmax, so their
+    (finite) garbage never contributes."""
+    v = jnp.swapaxes(pages[layer, block_tables], 2, 3)  # (B, MB, bs, H, ..)
     return v.reshape((v.shape[0], v.shape[1] * v.shape[2]) + v.shape[3:])
 
 
+_PAGE_OPERANDS = ("k_pages", "v_pages", "k2_pages", "k_scales", "v_scales",
+                  "k2_scales")
+
+
 def fused_paged_attention(q, k_pages, v_pages, block_tables, base, *,
-                          lengths=None, q2=None, k2_pages=None,
+                          layer=0, lengths=None, q2=None, k2_pages=None,
                           k_scales=None, v_scales=None, k2_scales=None,
                           **kw):
-    """``paged_decode_attention`` as the decode layers call it. Traced
-    under a serving mesh (``shard_rules.activation_mesh``), the kernel
-    runs per shard inside a ``shard_map`` — Mosaic kernels cannot be
-    partitioned automatically — with the specs of
-    ``shard_rules.paged_kernel_specs``; each shard attends its own slots
-    and kv-heads, so per-slot results are those of one device."""
+    """``paged_decode_attention`` as the decode layers call it, on the
+    stacked (L, ...) page leaves at ``layer``. Traced under a serving
+    mesh (``shard_rules.activation_mesh``), the kernel runs per shard
+    inside a ``shard_map`` — Mosaic kernels cannot be partitioned
+    automatically — with the specs of ``shard_rules.paged_kernel_specs``;
+    each shard attends its own slots and kv-heads, so per-slot results
+    are those of one device."""
     b, c = q.shape[:2]
     if lengths is None:
         lengths = jnp.full((b,), c, jnp.int32)
     ops = {"q": q, "k_pages": k_pages, "v_pages": v_pages,
            "block_tables": block_tables, "positions": base,
-           "lengths": lengths, "q2": q2, "k2_pages": k2_pages,
-           "k_scales": k_scales, "v_scales": v_scales,
+           "lengths": lengths, "layer": jnp.asarray(layer, jnp.int32),
+           "q2": q2,
+           "k2_pages": k2_pages, "k_scales": k_scales, "v_scales": v_scales,
            "k2_scales": k2_scales}
     ops = {k: v for k, v in ops.items() if v is not None}
     mesh = shard_rules.traced_serving_mesh()
     if mesh is None:
         return paged_decode_attention(**{"v_pages": None, **ops}, **kw)
+    if shard_rules.serving_degrees(mesh)[0] > 1:
+        # Pages may be split over 'data' and the kernel reads them whole:
+        # gather this layer's pages, not every layer's.
+        for k in _PAGE_OPERANDS:
+            if k in ops:
+                ops[k] = jax.lax.dynamic_slice_in_dim(ops[k], layer, 1)
+        ops["layer"] = jnp.int32(0)
     per_slot, pages, rows, out = shard_rules.paged_kernel_specs(
-        mesh, b, q.shape[2], k_pages.shape[1])
+        mesh, b, q.shape[2], k_pages.shape[2])
     spec = {"q": per_slot, "q2": per_slot, "block_tables": rows,
-            "positions": rows, "lengths": rows}
+            "positions": rows, "lengths": rows, "layer": P()}
     return jax.shard_map(
         lambda *xs: paged_decode_attention(
             **{"v_pages": None, **dict(zip(ops, xs))}, **kw),
@@ -439,12 +499,13 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                impl: str = "ref", interpret: bool = True,
                mrope_positions=None, cross: bool = False,
                block_tables=None, lengths=None,
-               paged_impl: str = "fused"):
+               paged_impl: str = "fused", layer=0):
     """Decode step over a chunk of C tokens. x: (B, C, d); ``position``:
     scalar int32 or (B,) base position (chunk entry i sits at base + i);
-    cache {"k","v"}: (B, S, Hkv, D) pre-allocated — or head-major
-    physical pages (NB, Hkv, bs, D) when ``block_tables`` (B, max_blocks)
-    is passed.
+    cache {"k","v"}: (B, S, Hkv, D) pre-allocated — or, when
+    ``block_tables`` (B, max_blocks) is passed, the arena's stacked
+    head-major pages (L, NB, Hkv, bs, D), of which this call writes and
+    reads layer ``layer`` in place.
     C == 1 is the classic one-token step. Returns (out, cache).
 
     ``lengths``: (B,) valid entries per row (chunked prefill: a slot may
@@ -467,28 +528,21 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
         kv_len = None
     elif block_tables is not None:
         kv_quant = isinstance(cache["k"], dict)   # {"q","s"} int8 leaves
-        if kv_quant:
-            kp = _paged_insert_quant(cache["k"], k, position, block_tables,
-                                     lengths)
-            vp = _paged_insert_quant(cache["v"], v, position, block_tables,
-                                     lengths)
-        else:
-            kp = paged_insert_token(cache["k"], k, position, block_tables,
-                                    lengths)
-            vp = paged_insert_token(cache["v"], v, position, block_tables,
-                                    lengths)
+        insert = _paged_insert_quant if kv_quant else paged_insert_token
+        kp = insert(cache["k"], k, position, block_tables, lengths, layer)
+        vp = insert(cache["v"], v, position, block_tables, lengths, layer)
         cache = {"k": kp, "v": vp}
         if paged_impl == "fused":
             base = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
             if kv_quant:
                 o = fused_paged_attention(
                     q, kp["q"], vp["q"], block_tables, base,
-                    sm_scale=hd ** -0.5, k_scales=kp["s"],
+                    sm_scale=hd ** -0.5, layer=layer, k_scales=kp["s"],
                     v_scales=vp["s"], lengths=lengths,
                     interpret=interpret)
             else:
                 o = fused_paged_attention(q, kp, vp, block_tables, base,
-                                          sm_scale=hd ** -0.5,
+                                          sm_scale=hd ** -0.5, layer=layer,
                                           lengths=lengths,
                                           interpret=interpret)
             o = o.reshape(b, cw, cfg.num_heads * hd)
@@ -496,11 +550,11 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                                       interpret=interpret)
             return out, cache
         if kv_quant:
-            kc = _paged_view_dequant(kp, block_tables).astype(q.dtype)
-            vc = _paged_view_dequant(vp, block_tables).astype(q.dtype)
+            kc = _paged_view_dequant(kp, block_tables, layer).astype(q.dtype)
+            vc = _paged_view_dequant(vp, block_tables, layer).astype(q.dtype)
         else:
-            kc = paged_view(kp, block_tables)
-            vc = paged_view(vp, block_tables)
+            kc = paged_view(kp, block_tables, layer)
+            vc = paged_view(vp, block_tables, layer)
         kv_len = pos_mat + 1                # per-query causal depth
     else:
         kc = _insert_kv(cache["k"], k, position, lengths)
@@ -599,7 +653,7 @@ def mla_prefill(p, cfg, x, positions, *, fmt="none", impl="ref",
 
 def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
                interpret=True, block_tables=None, lengths=None,
-               paged_impl: str = "fused"):
+               paged_impl: str = "fused", layer=0):
     """Absorbed-matmul MLA decode over a chunk of C tokens: the kv_b
     projection is folded into the query/output sides so the compressed
     cache is attended directly — no (B, S, H, D) expansion ever
@@ -607,9 +661,10 @@ def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
     the scalar/(B,) base and ``lengths`` the per-row valid count (chunked
     prefill), masking exactly like the GQA chunk path.
 
-    With ``block_tables``, cache leaves are physical pages (NB, 1, bs,
-    ...) — a unit head axis, the paged layout's shared shape — and the
-    compressed latents are scattered through the table. The
+    With ``block_tables``, cache leaves are the arena's stacked physical
+    pages (L, NB, 1, bs, ...) — a unit head axis, the paged layout's
+    shared shape — and the compressed latents are scattered through the
+    table into layer ``layer``, which is also the layer read. The
     default ``paged_impl="fused"`` attends the pages in place: the fused
     kernel takes the absorbed ``q_eff`` against the ckv pages (which are
     also V) and joins the decoupled-RoPE scores via its q2/k2 operands —
@@ -627,13 +682,13 @@ def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
         insert = _paged_insert_quant if kv_quant else paged_insert_token
         view = _paged_view_dequant if kv_quant else paged_view
         ckv_p = insert(cache["ckv"], ckv_new[:, :, None], position,
-                       block_tables, lengths)
+                       block_tables, lengths, layer)
         krope_p = insert(cache["krope"], krope_new[:, :, None], position,
-                         block_tables, lengths)
+                         block_tables, lengths, layer)
         cache = {"ckv": ckv_p, "krope": krope_p}
         if not fused:
-            ckv = view(ckv_p, block_tables)[:, :, 0]
-            krope = view(krope_p, block_tables)[:, :, 0]
+            ckv = view(ckv_p, block_tables, layer)[:, :, 0]
+            krope = view(krope_p, block_tables, layer)[:, :, 0]
     else:
         ckv = _insert_kv(cache["ckv"], ckv_new, position, lengths)
         krope = _insert_kv(cache["krope"], krope_new, position, lengths)
@@ -651,18 +706,18 @@ def mla_decode(p, cfg, x, position, cache, *, fmt="none", impl="ref",
     if fused:
         base = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
         if kv_quant:
-            # Quantized compressed latents: int8 code pages + (NP, 1, bs)
-            # scale pages — already the kernel's Hkv == 1 layout.
+            # Quantized compressed latents: int8 code pages + (L, NP, 1,
+            # bs) scale pages — already the kernel's Hkv == 1 layout.
             ctx = fused_paged_attention(
                 q_eff, ckv_p["q"], None,                 # ckv doubles as V
-                block_tables, base, sm_scale=sm,
+                block_tables, base, sm_scale=sm, layer=layer,
                 q2=q_rope.astype(jnp.float32), k2_pages=krope_p["q"],
                 k_scales=ckv_p["s"], k2_scales=krope_p["s"],
                 lengths=lengths, out_dtype=jnp.float32, interpret=interpret)
         else:
             ctx = fused_paged_attention(
                 q_eff, ckv_p, None,                      # ckv doubles as V
-                block_tables, base, sm_scale=sm,
+                block_tables, base, sm_scale=sm, layer=layer,
                 q2=q_rope.astype(jnp.float32), k2_pages=krope_p,
                 lengths=lengths, out_dtype=jnp.float32,
                 interpret=interpret)                     # (b,q,h,rank)

@@ -265,13 +265,20 @@ def encdec_decode_step(params, cfg: ModelConfig, token, position, cache, *,
     pos_mat = attn.decode_positions(position, b, cw)
     h = h + pe[pos_mat].astype(h.dtype)
 
+    # Paged self-attention takes a stack of layers: this scan hands it one
+    # layer's pages as a stack of one.
+    paged = block_tables is not None
+    stack = lambda c: jax.tree.map(lambda x: x[None], c) if paged else c
+    unstack = lambda c: jax.tree.map(lambda x: x[0], c) if paged else c
+
     def body(h, xs):
         lp, lc = xs
         hn = layers.layernorm_apply(lp["self_norm"], h)
         mix, self_cache = attn.gqa_decode(
-            lp["self_attn"], cfg, hn, position, lc["self"], fmt=fmt,
+            lp["self_attn"], cfg, hn, position, stack(lc["self"]), fmt=fmt,
             impl=impl, interpret=interpret, block_tables=block_tables,
             lengths=lengths, paged_impl=paged_impl)
+        self_cache = unstack(self_cache)
         h = h + mix
         hn = layers.layernorm_apply(lp["cross_norm"], h)
         q = layers.linear_apply(lp["cross_attn"]["q"], hn, fmt, impl=impl,

@@ -130,7 +130,7 @@ def layer_prefill(p: Params, cfg: ModelConfig, h, positions, *, mixer, ffn,
     return h, cache, aux
 
 
-def layer_decode(p: Params, cfg: ModelConfig, h, position, cache, *,
+def layer_decode(p: Params, cfg: ModelConfig, h, position, cache, layer, *,
                  mixer, ffn, fmt, impl, interpret, mrope_positions=None,
                  block_tables=None, lengths=None, paged_impl="fused"):
     """Decode layer step over a chunk of C tokens (C == 1 is the classic
@@ -138,19 +138,25 @@ def layer_decode(p: Params, cfg: ModelConfig, h, position, cache, *,
     paged-arena tables threaded to the attention mixers (SSM states are
     per-slot constants — paging does not apply); ``paged_impl`` selects
     the fused block-table kernel or the gather oracle. ``lengths``: (B,)
-    valid chunk entries per row (unified chunked prefill)."""
+    valid chunk entries per row (unified chunked prefill).
+
+    ``cache`` is this layer's own slice, or — for an attention mixer
+    with ``block_tables`` — its group's stacked pages, which the mixer
+    writes and reads at ``layer`` in place (``_in_place``)."""
     hn = layers.rmsnorm_apply(p["mixer_norm"], h, cfg.norm_eps)
     if mixer == "gqa":
         mix, cache = attn.gqa_decode(p["attn"], cfg, hn, position, cache,
                                      fmt=fmt, impl=impl, interpret=interpret,
                                      mrope_positions=mrope_positions,
                                      block_tables=block_tables,
-                                     lengths=lengths, paged_impl=paged_impl)
+                                     lengths=lengths, paged_impl=paged_impl,
+                                     layer=layer)
     elif mixer == "mla":
         mix, cache = attn.mla_decode(p["attn"], cfg, hn, position, cache,
                                      fmt=fmt, impl=impl, interpret=interpret,
                                      block_tables=block_tables,
-                                     lengths=lengths, paged_impl=paged_impl)
+                                     lengths=lengths, paged_impl=paged_impl,
+                                     layer=layer)
     else:
         mix, cache = ssm.ssm_decode(p["ssm"], cfg, hn, cache, fmt=fmt,
                                     impl=impl, interpret=interpret,
@@ -166,6 +172,15 @@ def layer_decode(p: Params, cfg: ModelConfig, h, position, cache, *,
                                     interpret=interpret)
         h = h + y
     return h, cache
+
+
+def _in_place(mixer: str, block_tables) -> bool:
+    """Whether a mixer's cache is paged and so stays stacked in the
+    decode scan's carry: attention leaves of a paged arena. Per-slot
+    leaves (contiguous K/V, SSM state) are the scan's ``xs``/``ys``,
+    which hand each layer its slice and take back whatever dtype it
+    emits (the f32 SSM state ``kvcache.step_leaf_dtypes`` probes)."""
+    return block_tables is not None and mixer != "ssm"
 
 
 def layer_cache_shape(cfg: ModelConfig, mixer: str, batch: int, seq: int):
@@ -436,32 +451,36 @@ def lm_decode_step(params, cfg: ModelConfig, token: jnp.ndarray,
         mrope_pos = _mrope_decode_positions(cfg, pos_mat)
     new_caches = {}
     for name, count, subs in layer_groups(cfg):
-        def body(h, xs, subs=subs):
-            lp, lc = xs
-            if len(subs) == 1:
-                h, c = layer_decode(lp, cfg, h, position, lc,
-                                    mixer=subs[0][0], ffn=subs[0][1],
-                                    fmt=fmt, impl=impl, interpret=interpret,
-                                    mrope_positions=mrope_pos,
-                                    block_tables=block_tables,
-                                    lengths=lengths, paged_impl=paged_impl)
-            else:
-                c = {}
-                for i, (mx, ff) in enumerate(subs):
-                    h, ci = layer_decode(lp[f"sub{i}"], cfg, h, position,
-                                         lc[f"sub{i}"], mixer=mx, ffn=ff,
-                                         fmt=fmt, impl=impl,
-                                         interpret=interpret,
-                                         mrope_positions=mrope_pos,
-                                         block_tables=block_tables,
-                                         lengths=lengths,
-                                         paged_impl=paged_impl)
-                    c[f"sub{i}"] = ci
-            return h, c
+        # Sub-layer caches by name (a one-sub group is its own "sub0").
+        # Paged leaves ride the carry whole, so the arena is never sliced
+        # per layer nor rebuilt as the scan's output; per-slot leaves are
+        # sliced in and stacked out as before.
+        keys = [f"sub{i}" for i in range(len(subs))]
+        group = cache[name] if len(subs) > 1 else {"sub0": cache[name]}
+        stacked = {k: group[k] for k, (mx, _) in zip(keys, subs)
+                   if _in_place(mx, block_tables)}
+        per_slot = {k: group[k] for k in keys if k not in stacked}
+
+        def body(carry, xs, subs=subs, keys=keys):
+            h, stacked = carry
+            lp, li, per_slot = xs
+            caches = {**stacked, **per_slot}
+            for k, (mx, ff) in zip(keys, subs):
+                h, caches[k] = layer_decode(
+                    lp[k] if len(subs) > 1 else lp, cfg, h, position,
+                    caches[k], li, mixer=mx, ffn=ff, fmt=fmt, impl=impl,
+                    interpret=interpret, mrope_positions=mrope_pos,
+                    block_tables=block_tables, lengths=lengths,
+                    paged_impl=paged_impl)
+            return ((h, {k: caches[k] for k in stacked}),
+                    {k: caches[k] for k in per_slot})
         with jax.named_scope("layers"):     # the body is traced inside
-            h, new_cache = jax.lax.scan(body, h, (params[name], cache[name]),
-                                         unroll=flags.inner_unroll())
-        new_caches[name] = new_cache
+            (h, stacked), per_slot = jax.lax.scan(
+                body, (h, stacked),
+                (params[name], jnp.arange(count, dtype=jnp.int32), per_slot),
+                unroll=flags.inner_unroll())
+        group = {**stacked, **per_slot}
+        new_caches[name] = group if len(subs) > 1 else group["sub0"]
     with jax.named_scope("lm_head"):
         h = layers.rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
         logits = _lm_head(params, cfg, h, quant, impl, interpret)

@@ -327,20 +327,21 @@ def traced_serving_mesh() -> Optional[Mesh]:
 def paged_kernel_specs(mesh: Mesh, batch: int, num_heads: int,
                        num_kv_heads: int) -> Tuple[P, P, P, P]:
     """shard_map specs for the fused paged-attention kernel, which Mosaic
-    cannot partition by itself: ``(per-slot (B, C, H, D), pages (NP, Hkv,
-    bs[, D]), tables/positions (B[, MB]), output)``.
+    cannot partition by itself: ``(per-slot (B, C, H, D), stacked pages
+    (L, NP, Hkv, bs[, D]), tables/positions (B[, MB]), output)``.
 
     Slots split over 'data' and kv-heads (with their query heads) over
     'model' where they divide. Pages are whole on every 'data' shard:
     block tables hold global page ids and the allocator does not keep a
     slot's pages on its own replica, so a data-sharded arena is gathered
-    for the kernel (reads only; inserts stay GSPMD scatters)."""
+    for the kernel, one layer at a time (reads only; inserts stay GSPMD
+    scatters)."""
     dp, tp = serving_degrees(mesh)
     data = "data" if dp > 1 and batch % dp == 0 else None
     model = "model" if tp > 1 and num_kv_heads % tp == 0 \
         and num_heads % tp == 0 else None
     per_slot = P(data, None, model)
-    return per_slot, P(None, model), P(data), per_slot
+    return per_slot, P(None, None, model), P(data), per_slot
 
 
 def constrain_replicated(x):

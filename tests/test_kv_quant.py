@@ -108,7 +108,8 @@ def _tables(rng, b, mb, nb, null_block, owned=None):
 
 
 def _to_pages(contig, tables, bs, num_pages):
-    """(B, S, H, ...) -> head-major (num_pages, H, bs, ...)."""
+    """(B, S, H, ...) -> a one-layer stack of head-major (1, num_pages,
+    H, bs, ...) pages."""
     shape = contig.shape
     pages = np.zeros((num_pages, shape[2], bs) + shape[3:],
                      np.asarray(contig).dtype)
@@ -118,7 +119,7 @@ def _to_pages(contig, tables, bs, num_pages):
                 continue
             pages[tables[i, j]] = np.swapaxes(
                 np.asarray(contig[i, j * bs:(j + 1) * bs]), 0, 1)
-    return jnp.asarray(pages)
+    return jnp.asarray(pages[None])
 
 
 @pytest.mark.parametrize("chunk", [1, 4, 5])
@@ -155,8 +156,8 @@ def test_kernel_requires_matched_scale_operands():
     """The scale operands travel as a set: v_pages without v_scales (or
     k2 without k2_scales) under quantization is a contract violation."""
     rng = np.random.RandomState(5)
-    pages = jnp.asarray(rng.randint(-127, 127, (3, 2, 1, 8)), jnp.int8)
-    scales = jnp.ones((3, 2, 1), jnp.float16)
+    pages = jnp.asarray(rng.randint(-127, 127, (1, 3, 2, 1, 8)), jnp.int8)
+    scales = jnp.ones((1, 3, 2, 1), jnp.float16)
     tb = jnp.asarray([[0, 1]], jnp.int32)
     q = jnp.asarray(rng.randn(1, 1, 2, 8).astype(np.float32))
     with pytest.raises(AssertionError):
@@ -187,8 +188,8 @@ def test_gqa_decode_quantized_fused_vs_ref(gqa_model, chunk):
     rng = np.random.RandomState(17)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"k": _quant_pages(k1, (nb + 1, hkv, bs, hd)),
-             "v": _quant_pages(k2, (nb + 1, hkv, bs, hd))}
+    cache = {"k": _quant_pages(k1, (1, nb + 1, hkv, bs, hd)),
+             "v": _quant_pages(k2, (1, nb + 1, hkv, bs, hd))}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([5, 9, 2], jnp.int32)
     lengths = jnp.asarray([chunk, max(chunk - 2, 1), chunk], jnp.int32)
@@ -221,8 +222,9 @@ def test_mla_decode_quantized_fused_vs_ref(mla_model, chunk):
     rng = np.random.RandomState(18)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"ckv": _quant_pages(k1, (nb + 1, 1, bs, m.kv_lora_rank)),
-             "krope": _quant_pages(k2, (nb + 1, 1, bs, m.qk_rope_head_dim))}
+    cache = {"ckv": _quant_pages(k1, (1, nb + 1, 1, bs, m.kv_lora_rank)),
+             "krope": _quant_pages(k2,
+                                   (1, nb + 1, 1, bs, m.qk_rope_head_dim))}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([7, 3], jnp.int32)
     lengths = jnp.asarray([chunk, max(chunk - 1, 1)], jnp.int32)

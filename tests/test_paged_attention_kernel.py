@@ -70,8 +70,8 @@ def _tables(rng, b, mb, nb, null_block, owned=None):
 
 
 def _to_pages(contig, tables, bs, num_pages):
-    """(B, S, H, ...) -> head-major (num_pages, H, bs, ...) per a block
-    table (null page left zero)."""
+    """(B, S, H, ...) -> a one-layer stack of head-major (1, num_pages,
+    H, bs, ...) pages per a block table (null page left zero)."""
     shape = contig.shape
     pages = np.zeros((num_pages, shape[2], bs) + shape[3:],
                      np.asarray(contig).dtype)
@@ -81,7 +81,7 @@ def _to_pages(contig, tables, bs, num_pages):
                 continue
             pages[tables[i, j]] = np.swapaxes(
                 np.asarray(contig[i, j * bs:(j + 1) * bs]), 0, 1)
-    return jnp.asarray(pages)
+    return jnp.asarray(pages[None])
 
 
 def _ref_gqa(q, k_pages, v_pages, tables, pos0, sm):
@@ -155,6 +155,90 @@ def test_kernel_matches_gather_ref_mla(chunk):
 
 
 # ----------------------------------------------------------------------
+# Stacked arena: the kernel reads layer ``layer`` of (L, NP, ...) in place
+# ----------------------------------------------------------------------
+def _stacked(rng, tables, shape, bs, num_pages, layers, kv):
+    """``layers`` layers of random pages (float32 values in a bf16 arena,
+    or int8 codes with their scale pages): a dict {"q"[, "s"]} of stacked
+    (L, num_pages, H, bs, ...) leaves."""
+    per_layer = []
+    for _ in range(layers):
+        x = jnp.asarray(rng.randn(*shape).astype(np.float32))
+        parts = dict(zip("qs", attn.quantize_kv(x))) if kv == "int8" \
+            else {"q": x.astype(jnp.bfloat16)}
+        per_layer.append({k: _to_pages(v, tables, bs, num_pages)
+                          for k, v in parts.items()})
+    return {k: jnp.concatenate([p[k] for p in per_layer])
+            for k in per_layer[0]}
+
+
+def _layer_view(leaf, tables, layer):
+    """f32 dense gather of one layer of a stacked leaf (the oracle)."""
+    if "s" in leaf:
+        return attn._paged_view_dequant(leaf, tables, layer)
+    return attn.paged_view(leaf["q"], tables, layer).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_kernel_reads_stacked_layer(kind, kv, layer):
+    """On a 3-layer arena the kernel at ``layer`` is the per-layer kernel
+    on that layer's pages, bit for bit, and matches the dense gather of
+    that layer; the other layers hold different values, so a wrong layer
+    index cannot pass."""
+    B, H, bs, mb, chunk = 2, 4, 4, 5, 3
+    hkv, d, d2 = (2, 16, None) if kind == "gqa" else (1, 16, 8)
+    nb = B * mb
+    rng = np.random.RandomState(31 + layer)
+    owned = [mb, 3]
+    tables = _tables(rng, B, mb, nb, null_block=nb, owned=owned)
+    tb = jnp.asarray(tables)
+    mk = lambda dim: _stacked(rng, tables, (B, mb * bs, hkv, dim), bs,
+                              nb + 1, 3, kv)
+    k, v = mk(d), (mk(d) if kind == "gqa" else None)
+    k2 = mk(d2) if kind == "mla" else None
+    q = jnp.asarray(rng.randn(B, chunk, H, d).astype(np.float32))
+    q2 = jnp.asarray(rng.randn(B, chunk, H, d2).astype(np.float32)) \
+        if kind == "mla" else None
+    pos0 = jnp.asarray([max(o * bs - chunk, 0) for o in owned], jnp.int32)
+    sm = d ** -0.5
+
+    def run(lyr, pick):
+        ops = {"k_pages": pick(k["q"]),
+               "v_pages": None if v is None else pick(v["q"])}
+        if kind == "mla":
+            ops.update(q2=q2, k2_pages=pick(k2["q"]))
+        if kv == "int8":
+            ops.update(k_scales=pick(k["s"]))
+            if v is not None:
+                ops.update(v_scales=pick(v["s"]))
+            if k2 is not None:
+                ops.update(k2_scales=pick(k2["s"]))
+        return np.asarray(paged_decode_attention(
+            q, block_tables=tb, positions=pos0, sm_scale=sm, layer=lyr,
+            out_dtype=jnp.float32, interpret=True, **ops))
+
+    out = run(layer, lambda x: x)
+    np.testing.assert_array_equal(out, run(0, lambda x: x[layer][None]))
+    kc = _layer_view(k, tb, layer)
+    if kind == "gqa":
+        pos_mat = attn.decode_positions(pos0, B, chunk)
+        ref = attn.decode_attention(q, kc, _layer_view(v, tb, layer),
+                                    sm_scale=sm, kv_len=pos_mat + 1)
+    else:
+        ckv, kr = kc[:, :, 0], _layer_view(k2, tb, layer)[:, :, 0]
+        s = (jnp.einsum("bqhr,bsr->bhqs", q, ckv)
+             + jnp.einsum("bqhe,bse->bhqs", q2, kr)) * sm
+        pos_mat = attn.decode_positions(pos0, B, chunk)
+        mask = jnp.arange(ckv.shape[1])[None, None, None, :] \
+            < (pos_mat + 1)[:, None, :, None]
+        p = jax.nn.softmax(jnp.where(mask, s, attn.NEG_INF), axis=-1)
+        ref = jnp.einsum("bhqs,bsr->bqhr", p, ckv)
+    np.testing.assert_allclose(out, np.asarray(ref), **FP32_TOL)
+
+
+# ----------------------------------------------------------------------
 # Layer-level: gqa_decode / mla_decode fused vs ref impl
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("chunk", [1, 4, 5])
@@ -168,8 +252,10 @@ def test_gqa_decode_fused_vs_ref_layer(gqa_model, chunk):
     rng = np.random.RandomState(7)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"k": jax.random.normal(k1, (nb + 1, hkv, bs, hd), jnp.float32),
-             "v": jax.random.normal(k2, (nb + 1, hkv, bs, hd), jnp.float32)}
+    cache = {"k": jax.random.normal(k1, (1, nb + 1, hkv, bs, hd),
+                                    jnp.float32),
+             "v": jax.random.normal(k2, (1, nb + 1, hkv, bs, hd),
+                                    jnp.float32)}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([5, 9, 2], jnp.int32)
     lengths = jnp.asarray([chunk, max(chunk - 2, 1), chunk], jnp.int32)
@@ -202,10 +288,12 @@ def test_mla_decode_fused_vs_ref_layer(mla_model, chunk):
     rng = np.random.RandomState(8)
     tables = jnp.asarray(_tables(rng, B, mb, nb, null_block=nb))
     k1, k2, k3 = jax.random.split(key, 3)
-    cache = {"ckv": jax.random.normal(k1, (nb + 1, 1, bs, m.kv_lora_rank),
+    cache = {"ckv": jax.random.normal(k1,
+                                      (1, nb + 1, 1, bs, m.kv_lora_rank),
                                       jnp.float32),
              "krope": jax.random.normal(k2,
-                                        (nb + 1, 1, bs, m.qk_rope_head_dim),
+                                        (1, nb + 1, 1, bs,
+                                         m.qk_rope_head_dim),
                                         jnp.float32)}
     x = jax.random.normal(k3, (B, chunk, cfg.d_model), jnp.float32)
     pos0 = jnp.asarray([7, 3], jnp.int32)
@@ -254,8 +342,8 @@ def test_dead_pages_cannot_affect_output():
     live = {int(p) for i in range(B) for p in tables[i, :owned[i]]}
     dead = [p for p in range(nb + 1) if p not in live]
     k_trash, v_trash = k_pages.copy(), v_pages.copy()
-    k_trash[dead] = 1e9                # huge finite garbage
-    v_trash[dead] = -1e9
+    k_trash[:, dead] = 1e9             # huge finite garbage
+    v_trash[:, dead] = -1e9
     trashed = run(k_trash, v_trash)
     np.testing.assert_array_equal(base, trashed)
 

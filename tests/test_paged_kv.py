@@ -266,9 +266,9 @@ def test_request_rejects_degenerate_prompts():
 # Differential: paged decode == contiguous decode (GQA + MLA)
 # ----------------------------------------------------------------------
 def _scatter_to_pages(contig, tables, bs, nb):
-    """(B, S, H, ...) -> head-major (NB+1, H, bs, ...) pages per a
-    (B, MB) block table; a cache without a head axis (MLA latents) gets
-    the unit one."""
+    """(B, S, H, ...) -> a one-layer stack of head-major (1, NB+1, H, bs,
+    ...) pages per a (B, MB) block table; a cache without a head axis
+    (MLA latents) gets the unit one."""
     if contig.ndim == 3:
         contig = contig[:, :, None]
     pages = np.zeros((nb + 1, contig.shape[2], bs) + contig.shape[3:],
@@ -277,7 +277,7 @@ def _scatter_to_pages(contig, tables, bs, nb):
         for j in range(tables.shape[1]):
             pages[tables[i, j]] = np.swapaxes(
                 np.asarray(contig[i, j * bs:(j + 1) * bs]), 0, 1)
-    return jnp.asarray(pages)
+    return jnp.asarray(pages[None])
 
 
 def _random_tables(rng, b, mb, nb):
@@ -371,6 +371,52 @@ def test_paged_mla_decode_matches_contiguous(mla_model, dtype, exact):
         np.testing.assert_allclose(np.asarray(out_c, np.float32),
                                    np.asarray(out_f, np.float32),
                                    atol=1e-1, rtol=1e-1)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("heads", [2, 1])        # GQA K/V; MLA latents
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+@pytest.mark.parametrize("feed", ["chunk", "token"])
+def test_paged_insert_in_place_at_layer(feed, kv, heads, layer):
+    """The insert writes row (layer, page, head, offset) of the stacked
+    arena for each valid (slot, chunk entry) and nothing else: every
+    other layer, every untouched row and every dropped tail write stay
+    bit-identical. A chunk that crosses a block edge puts entries of one
+    slot on two pages, so its writes span two tiles."""
+    L, nb, bs, d = 3, 10, 4, 8
+    rng = np.random.RandomState(23 + layer + 2 * heads)
+    tables = np.stack([rng.permutation(nb)[:3] for _ in range(3)]) \
+        .astype(np.int32)
+    tables[2] = nb                               # an idle slot: null page
+    if feed == "chunk":                          # b0 crosses a block edge
+        c, pos0, lengths = 5, [2, 5, 0], jnp.asarray([5, 2, 0], jnp.int32)
+    else:
+        c, pos0, lengths = 1, [6, 3, 1], None
+    new = rng.randn(3, c, heads, d).astype(np.float32)
+    dense = rng.randn(L, nb + 1, heads, bs, d).astype(np.float32)
+    if kv == "int8":
+        q, sc = attn.quantize_kv(jnp.asarray(dense))
+        pages = {"q": q, "s": sc}
+        got = attn._paged_insert_quant(pages, jnp.asarray(new),
+                                       jnp.asarray(pos0), jnp.asarray(tables),
+                                       lengths, layer)
+        nq, ns = attn.quantize_kv(jnp.asarray(new))
+        writes = {"q": np.asarray(nq), "s": np.asarray(ns)}
+    else:
+        pages = {"x": jnp.asarray(dense)}
+        got = {"x": attn.paged_insert_token(
+            pages["x"], jnp.asarray(new), jnp.asarray(pos0),
+            jnp.asarray(tables), lengths, layer)}
+        writes = {"x": new}
+    n = [c] * 3 if lengths is None else list(np.asarray(lengths))
+    for part, arr in pages.items():
+        want = np.array(arr)
+        for b in range(3):
+            for i in range(n[b]):
+                pos = pos0[b] + i
+                want[layer, tables[b, pos // bs], :, pos % bs] = \
+                    writes[part][b, i]
+        np.testing.assert_array_equal(np.asarray(got[part]), want)
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "deepseek-v3-671b"])

@@ -8,12 +8,16 @@ and a 2-layer published-width ``qwen3-1.7b`` decode step on the paged
 fused path — on one chip and on a dp=2 x tp=2 serving mesh over the
 four chips, where the kernel runs inside a ``shard_map`` (Mosaic kernels
 cannot be partitioned automatically) — with the kernel present as a
-``tpu_custom_call``.
+``tpu_custom_call``. At the one-chip cell's engine shape the compiled
+step is also searched for any copy, slice or update-slice of a value
+the size of an arena leaf or of one layer's pages: the paged arena must
+stay in place, in its own layout, through the whole step.
 
 The topology is described inside a module fixture (never at import):
 only the worker that runs this file loads the TPU compiler library.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,7 +88,7 @@ def test_paged_kernel_compiles_gqa_qwen3_1_7b(one_chip, no_persistent_cache,
     h, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim()
     spec = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
     page_dt = jnp.bfloat16 if kv == "bf16" else jnp.int8
-    pages = spec((NUM_PAGES, hkv, BLOCK, d), page_dt)
+    pages = spec((1, NUM_PAGES, hkv, BLOCK, d), page_dt)
     q = spec((SLOTS, CHUNK, h, d), jnp.bfloat16)
     if kv == "bf16":
         def fn(q, k, v, t, p, n):
@@ -92,7 +96,7 @@ def test_paged_kernel_compiles_gqa_qwen3_1_7b(one_chip, no_persistent_cache,
                                           lengths=n, interpret=False)
         args = (q, pages, pages)
     else:
-        scales = spec((NUM_PAGES, hkv, BLOCK), jnp.float16)
+        scales = spec((1, NUM_PAGES, hkv, BLOCK), jnp.float16)
 
         def fn(q, k, v, ks, vs, t, p, n):
             return paged_decode_attention(q, k, v, t, p, sm_scale=d ** -0.5,
@@ -114,9 +118,9 @@ def test_paged_kernel_compiles_mla_deepseek_v3(one_chip, no_persistent_cache,
     page_dt = jnp.bfloat16 if kv == "bf16" else jnp.int8
     q1 = spec((SLOTS, 1, h, rank), jnp.float32)
     q2 = spec((SLOTS, 1, h, rope), jnp.float32)
-    ckv = spec((NUM_PAGES, 1, BLOCK, rank), page_dt)
-    krope = spec((NUM_PAGES, 1, BLOCK, rope), page_dt)
-    scales = spec((NUM_PAGES, 1, BLOCK), jnp.float16)
+    ckv = spec((1, NUM_PAGES, 1, BLOCK, rank), page_dt)
+    krope = spec((1, NUM_PAGES, 1, BLOCK, rope), page_dt)
+    scales = spec((1, NUM_PAGES, 1, BLOCK), jnp.float16)
     sm = (m.mla.qk_nope_head_dim + rope) ** -0.5
 
     def fn(q1, q2, ckv, krope, cs, ks, t, p, n):
@@ -177,3 +181,73 @@ def test_decode_step_compiles_qwen3_1_7b_two_layers(
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem is None or mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+# The engine shape of the ``qwen3-1.7b.chat`` cell: 32 slots, chunk 16,
+# 256-token blocks, 200 blocks.
+CELL_SLOTS, CELL_CHUNK, CELL_MAX_SEQ, CELL_BLOCK, CELL_BLOCKS = \
+    32, 16, 4096, 256, 200
+_MOVES = {"copy", "dynamic-slice", "dynamic-update-slice"}
+_HLO_RESULT = re.compile(
+    r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\](?:\{[^}]*\})? "
+    r"([\w\-]+)\(")
+
+
+def _arena_moves(hlo_text, shapes):
+    """Lines of a compiled module that copy, slice or update-slice a value
+    shaped like one of ``shapes``: plain ops by opcode, fusions by the ops
+    the compiler names them after (``copy_bitcast_fusion``,
+    ``constant_dynamic-slice_fusion``, ...). Asynchronous moves between
+    memory spaces (``copy-start``/``copy-done``) are not among them."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if m is None:
+            continue
+        name, dims, op = m.groups()
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        kinds = set(name.split(".")[0].split("_")) if op == "fusion" \
+            else {op}
+        if dims in shapes and kinds & _MOVES:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_decode_step_keeps_arena_in_place(one_chip, no_persistent_cache,
+                                          monkeypatch, kv):
+    """The published-width qwen3-1.7b step (2 layers) at the cell's
+    engine shape, arena donated as the engine donates it: no op copies,
+    slices or update-slices a whole arena leaf or one layer's page array,
+    and the step's temporaries stay below one layer's page array. A layer
+    scan that takes the arena as ``xs`` and rebuilds it as ``ys``, or an
+    insert that scatters in a layout other than the arena's, fails both."""
+    monkeypatch.setattr(kernel_common, "interpret_kernels", lambda: False)
+    cfg = dataclasses.replace(get_config("qwen3-1.7b"), num_layers=2)
+    model = build_model(cfg)
+    kw = {} if kv == "bf16" else {"kv_quant": "int8"}
+    specs = model.chunked_step_specs(CELL_SLOTS, CELL_CHUNK, CELL_MAX_SEQ,
+                                     block_size=CELL_BLOCK,
+                                     num_blocks=CELL_BLOCKS, **kw)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+
+    def step(p, tokens, pos, lengths, cache, tables):
+        return model.decode_step(
+            p, tokens, pos, cache, lengths=lengths, block_tables=tables,
+            paged_impl="fused", interpret=kernel_common.interpret_kernels())
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(
+        jax.tree.map(place, model.abstract_params()),
+        place(specs["tokens"]), place(specs["positions"]),
+        place(specs["lengths"]), jax.tree.map(place, specs["cache"]),
+        place(specs["block_tables"])).compile()
+
+    leaves = jax.tree.leaves(specs["cache"])
+    shapes = {tuple(x.shape) for x in leaves} | \
+        {tuple(x.shape[1:]) for x in leaves}
+    assert _arena_moves(compiled.as_text(), shapes) == []
+    layer_pages = max(np.prod(x.shape[1:]) * x.dtype.itemsize
+                      for x in leaves)
+    mem = compiled.memory_analysis()
+    assert mem is not None
+    assert mem.temp_size_in_bytes < layer_pages
